@@ -1,107 +1,32 @@
-"""JAX version compatibility layer.
+"""The repo's defaults for JAX's mesh and shard_map constructors.
 
-The codebase is written against the modern JAX API (``jax.shard_map`` with
-``check_vma=`` and ``axis_names=``, ``jax.make_mesh`` with ``axis_types=``).
-On JAX 0.4.x those do not exist; this module shims them down:
-
-  * ``shard_map``  -> ``jax.experimental.shard_map.shard_map``, translating
-    ``check_vma=`` to ``check_rep=``. Partial-manual mode (``axis_names=`` a
-    strict subset of the mesh) is unusable on 0.4.x: the XLA CPU SPMD
-    partitioner rejects the manual-subgroup collectives it produces
-    (``PartitionId instruction is not supported``, hard aborts on
-    ``ppermute``). We fall back to FULL manual over every mesh axis and
-    register the axes with ``repro.parallel.sharding`` so in-model sharding
-    constraints — performance hints on the auto axes — are dropped instead
-    of naming manual axes. Numerics are unchanged; compute that would have
-    been tensor-parallel on the auto axes is replicated instead.
-  * ``make_mesh``  -> ``jax.make_mesh`` without ``axis_types=``; every axis
-    in this repo is ``AxisType.Auto``, which is 0.4.x's only behaviour.
-
-Supported JAX range: 0.4.35 (first release with ``jax.make_mesh``) through
-current. All repo code must import ``shard_map``/``make_mesh`` from here,
-never from ``jax`` directly.
+Every mesh here has Auto axes (sharding propagates through the compiler
+outside shard_map), while ``jax.make_mesh`` defaults to Explicit axes; and
+every shard_map here runs with ``check_vma=False``. Build meshes and
+shard_maps through these two wrappers so the defaults stay in one place.
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence, Set
 
 import jax
-
-_HAS_MODERN_SHARD_MAP = hasattr(jax, "shard_map")
-
-try:  # jax >= 0.5: mesh axes carry an explicit AxisType
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # jax 0.4.x: implicit Auto everywhere
-    _AxisType = None
+from jax.sharding import AxisType
 
 
 def shard_map(f, *, mesh, in_specs, out_specs,
               axis_names: Optional[Set[str]] = None,
               check_vma: bool = False):
-    """``jax.shard_map`` on every supported JAX version.
-
-    ``axis_names`` is the modern meaning: the mesh axes under manual
-    control (None = all of them). ``check_vma`` maps to 0.4.x
-    ``check_rep``.
-    """
-    if _HAS_MODERN_SHARD_MAP:
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    from repro.parallel import sharding as _sh
-
-    def traced(*args, **kwargs):
-        # runs at trace time: tell the constraint helpers every mesh axis is
-        # manual here (no abstract mesh to ask on 0.4.x)
-        prev = _sh.set_manual_override(mesh.axis_names)
-        try:
-            return f(*args, **kwargs)
-        finally:
-            _sh.set_manual_override(prev)
-
-    return _shard_map(traced, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every version (0.4.x
-    returns a single-element list of per-program dicts)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
-def axis_size(axis_name: str) -> int:
-    """``jax.lax.axis_size`` (missing on 0.4.x: psum of 1 over the axis)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """``jax.shard_map``; ``axis_names`` are the mesh axes under manual
+    control (None = all of them)."""
+    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+              check_vma=check_vma)
+    if axis_names is not None:
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kw)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None):
-    """``jax.make_mesh`` with every axis Auto, on every supported version."""
-    if _AxisType is not None:
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices,
-                             axis_types=(_AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-
-
-def mesh_from_devices(device_grid, axis_names: Sequence[str]):
-    """A Mesh over an EXPLICIT device grid, every axis Auto.
-
-    ``jax.make_mesh`` routes through ``mesh_utils.create_device_mesh``,
-    which is free to reorder devices for locality — the right default,
-    but fatal for a TUNED placement whose device order is the artifact's
-    contract. The tuned mesh-mapping path builds here instead: the grid
-    is taken verbatim."""
-    axis_names = tuple(axis_names)
-    if _AxisType is not None:
-        return jax.sharding.Mesh(
-            device_grid, axis_names,
-            axis_types=(_AxisType.Auto,) * len(axis_names))
-    return jax.sharding.Mesh(device_grid, axis_names)
+    """``jax.make_mesh`` with every axis Auto."""
+    return jax.make_mesh(axis_shapes, axis_names, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axis_names))

@@ -143,9 +143,8 @@ class MeshMapping:
     def build_mesh(self, devices=None):
         """The mapped mesh over ``devices`` (default: all attached jax
         devices), id-sorted then permuted by ``device_order``."""
-        from repro import compat
+        import jax
         if devices is None:
-            import jax
             devices = jax.devices()
         devs = _sorted_devices(list(devices))
         if len(devs) != len(self.device_order):
@@ -155,10 +154,10 @@ class MeshMapping:
         arr = np.empty(len(devs), dtype=object)
         for slot, phys in enumerate(self.device_order):
             arr[slot] = devs[phys]
-        # explicit-order construction: jax.make_mesh may reorder devices
-        # for locality, which would silently undo the tuned placement
-        return compat.mesh_from_devices(arr.reshape(self.shape),
-                                        self.axes)
+        # explicit-order construction (Auto axes, like compat.make_mesh):
+        # jax.make_mesh may reorder devices for locality, which would
+        # silently undo the tuned placement
+        return jax.sharding.Mesh(arr.reshape(self.shape), tuple(self.axes))
 
     # -- serialization (the TableMeta.mapping field) ------------------------
     def to_json(self) -> dict:

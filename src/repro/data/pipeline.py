@@ -11,6 +11,7 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
+import zlib
 from typing import Iterator, Optional
 
 import numpy as np
@@ -62,7 +63,8 @@ class SyntheticPipeline:
         out = {}
         for name, (shp, dt) in self._shapes.items():
             n = int(np.prod(shp))
-            stream = hash(name) & 0x7FFFFFFF
+            # crc32, not hash(): str hashes change from process to process
+            stream = zlib.crc32(name.encode()) & 0x7FFFFFFF
             if str(dt) in ("int32",) or "int" in str(dt):
                 arr = _hash_tokens(self.seed, stream, step * n, n,
                                    cfg.vocab_size).reshape(shp)

@@ -9,6 +9,10 @@ survey's "avoid work the schedule proves unnecessary" tuning.
 Block shapes are the tunable: (block_q, block_k) default to (128, 128) so the
 score tile is MXU-aligned; VMEM working set per step is
 ``block_q*D + 2*block_k*D + block_q*block_k`` fp32 words.
+
+Training differentiates through a ``jax.custom_vjp``: the forward is this
+kernel, the backward is ``BACKWARD`` — the ``jax.vjp`` of the query-chunked
+XLA oracle ``ref.attention_xla_chunked``, recomputed from (q, k, v).
 """
 from __future__ import annotations
 
@@ -19,7 +23,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import ref
+
 NEG_INF = -1e30
+
+#: What computes the gradient of :func:`flash_attention`.
+BACKWARD = "jax.vjp of ref.attention_xla_chunked (XLA)"
 
 
 def _fa_kernel(
@@ -108,6 +117,35 @@ def flash_attention(
     block_k: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
+    """Masked GQA attention with the kernel as forward; gradients come
+    from ``BACKWARD``."""
+    static = (causal, window, q_offset, scale, block_q, block_k, interpret)
+    return _flash(q, k, v, static)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash(q, k, v, static):
+    return _flash_forward(q, k, v, *static)
+
+
+def _flash_fwd(q, k, v, static):
+    return _flash_forward(q, k, v, *static), (q, k, v)
+
+
+def _flash_bwd(static, res, g):
+    causal, window, q_offset, scale = static[:4]
+    _, vjp = jax.vjp(
+        functools.partial(ref.attention_xla_chunked, causal=causal,
+                          window=window, q_offset=q_offset, scale=scale),
+        *res)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _flash_forward(q, k, v, causal, window, q_offset, scale, block_q,
+                   block_k, interpret):
     B, S, H, D = q.shape
     T, KV = k.shape[1], k.shape[2]
     group = H // KV

@@ -197,7 +197,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
         current one (the query sits at absolute position ``length - 1``).
     """
     if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+        from repro.kernels.ops import on_tpu
+        impl = "pallas" if on_tpu() else "xla"
     if impl == "xla":
         return _paged_attention_xla(q, k_pool, v_pool, block_tables,
                                     lengths, window=window)

@@ -72,7 +72,7 @@ def accounting_metrics(cfg, shape, parallel, coll, mesh, **kw) -> dict:
                                                 mesh, accounting=True, **kw)
         compiled = jax.jit(fn, in_shardings=in_sh,
                            out_shardings=out_sh).lower(*args).compile()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         coll_b = ha.collective_bytes(compiled.as_text())
         return {
             "flops": float(cost.get("flops", 0)),
@@ -158,7 +158,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     rec["memory"]["peak_bytes_per_device"] = int(peak)
     rec["fits_16gb_hbm"] = bool(peak < 16e9)
 
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     txt = compiled.as_text()
     coll_b = ha.collective_bytes(txt)
     rec["cost_raw"] = {"flops": float(cost.get("flops", 0)),

@@ -12,7 +12,8 @@ Two modes share one model API and one tuned-collective path:
 With ``--tensor-parallel N --tuning-table ART`` either mode's per-token
 logits assembly goes through the `Communicator`'s {algorithm, segments}
 choice — bit-identical to the untuned loop, but executing the tuned wire
-schedule. Decode messages are KB-scale, so they resolve through the
+schedule (without ``--tuning-table`` the same loop runs XLA's own
+collectives). Decode messages are KB-scale, so they resolve through the
 small-message end of the tuning grid; the printed decode plan is
 `Communicator.explain` over the same requests the step executes.
 ``--probe-fabric`` probes the live fabric first so a multi-backend
@@ -37,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHITECTURES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build_model
 
 
@@ -105,9 +107,13 @@ def _serve_continuous(args, cfg, api, params, comm, mesh):
                    "view_len": view_len, "slo_ms": args.slo_ms,
                    "wall_s": res.wall_s, **s, "requests": res.records})
         print(f"decode summary -> {args.trace_dir}/decode_summary.json")
+    return res
 
 
-def main():
+def main(argv=None):
+    """Serve from CLI arguments (``argv``, default ``sys.argv[1:]``).
+    Returns the `ServeResult` with ``--continuous``, else the generated
+    tokens as a (batch, gen) array."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m",
                     choices=sorted(ARCHITECTURES))
@@ -141,9 +147,10 @@ def main():
                          "--tensor-parallel, drives the decode loop's "
                          "logits collective through it")
     ap.add_argument("--tensor-parallel", type=int, default=0,
-                    help=">=2: run the tuned TP decode path over a 'model' "
-                         "mesh axis of this size (requires --tuning-table "
-                         "and that many devices)")
+                    help=">=2: run the TP decode path over a 'model' mesh "
+                         "axis of this size (needs that many devices); "
+                         "its logits collective is tuned with "
+                         "--tuning-table, XLA's own without")
     ap.add_argument("--tp-collective", default="all_gather",
                     choices=("all_gather", "all_reduce"),
                     help="which tuned collective assembles the TP logits")
@@ -155,7 +162,12 @@ def main():
                     help="write decode_summary.json here (per-token "
                          "latency percentiles + throughput + config; "
                          "with --continuous also per-request spans)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
+    # serving places its arrays itself; a training mesh left by an earlier
+    # launch in this process must not steer the model's sharding hints
+    from repro.parallel import sharding as sh
+    sh.set_current_mesh(None)
 
     cfg = ARCHITECTURES[args.arch]
     if args.reduced:
@@ -178,9 +190,7 @@ def main():
         print(f"  decode plan p={p}")
         print(tp_decode_plan(comm, batch, cfg.d_model,
                              cfg.vocab_size, p).render(indent="    "))
-    api = build_model(cfg, window=args.window,
-                      attn_impl="xla" if jax.default_backend() != "tpu"
-                      else "auto")
+    api = build_model(cfg, window=args.window)
     params = api.init(jax.random.PRNGKey(0))
     B = args.batch
     cache_len = args.prompt_len + args.gen
@@ -188,7 +198,7 @@ def main():
     mesh = None
     if args.tensor_parallel >= 2:
         if comm is None:
-            raise SystemExit("--tensor-parallel needs --tuning-table")
+            comm = Communicator.create()      # XLA's own collectives
         from repro import compat
         from repro.launch.tp_decode import executed_spec
         tp = args.tensor_parallel
@@ -201,13 +211,13 @@ def main():
         batch = args.max_active if args.continuous else args.batch
         nbytes, spec = executed_spec(comm, args.tp_collective,
                                      batch, cfg.vocab_size, tp)
-        print(f"tensor-parallel decode: p={tp} via tuned "
+        how = "tuned " if args.tuning_table else ""
+        print(f"tensor-parallel decode: p={tp} via {how}"
               f"{args.tp_collective} ({nbytes} B -> {spec.algorithm} "
               f"segments={spec.segments})")
 
     if args.continuous:
-        _serve_continuous(args, cfg, api, params, comm, mesh)
-        return
+        return _serve_continuous(args, cfg, api, params, comm, mesh)
 
     # ---- fixed-batch validation oracle ----------------------------------
     if mesh is not None:
@@ -275,6 +285,7 @@ def main():
                    "token_ms_p90": float(p90),
                    "token_ms_p99": float(p99)})
         print(f"decode summary -> {args.trace_dir}/decode_summary.json")
+    return np.asarray(gen)
 
 
 if __name__ == "__main__":

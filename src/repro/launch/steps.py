@@ -102,8 +102,7 @@ def build_train_step(
         ep_axis=ep_axis,
         mesh=mesh,
         remat=(parallel.remat != "none"),
-        attn_impl="ref" if accounting else
-        ("xla" if jax.default_backend() != "tpu" else "auto"),
+        attn_impl="ref" if accounting else "auto",
         # per-layer release points need the unrolled layer stack: a scan
         # traces its body once, so its collectives can't overlap across
         # iterations
@@ -203,7 +202,7 @@ def build_train_step(
             contribution). pmean over ``ep_axis`` restores the
             replicated grads exactly (sum over ranks = tp x the true
             gradient); expert shards just divide by tp."""
-            tp = compat.axis_size(ep_axis)
+            tp = jax.lax.axis_size(ep_axis)
             especs = sh.ep_param_specs(params, ep_axis)
             return jax.tree.map(
                 lambda g, s: g / tp if s != P()
@@ -263,8 +262,7 @@ def build_prefill_step(cfg: ModelConfig, shape: ShapeConfig,
     comm = communicator or Communicator.from_config(coll, mesh)
     ep_axis = "model" if (cfg.family == "moe"
                           and sh.model_size(mesh) > 1) else None
-    ai = "ref" if accounting else \
-        ("xla" if jax.default_backend() != "tpu" else "auto")
+    ai = "ref" if accounting else "auto"
     api = build_model(
         cfg, ep_axis=ep_axis, mesh=mesh, param_dtype=jnp.bfloat16,
         attn_impl=ai, unroll=accounting, a2a_algorithm=comm)
@@ -333,8 +331,7 @@ def build_decode_step(cfg: ModelConfig, shape: ShapeConfig,
     api = build_model(
         cfg, window=plan.window, ep_axis=None, mesh=mesh,
         param_dtype=jnp.bfloat16, unroll=accounting,
-        attn_impl="ref" if accounting else
-        ("xla" if jax.default_backend() != "tpu" else "auto"))
+        attn_impl="ref" if accounting else "auto")
 
     key = jax.random.PRNGKey(0)
     params_s = jax.eval_shape(api.init, key)

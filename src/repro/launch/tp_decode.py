@@ -18,9 +18,9 @@ the untuned path (asserted in tests/test_decode_consistency.py):
     the tuned sum combines disjoint supports — adding exact zeros never
     perturbs the surviving addend.
 
-On JAX 0.4.x the model compute inside shard_map is replicated (the compat
-layer's documented fallback); the collectives still execute the tuned wire
-schedule, which is what the decision artifact tunes.
+The model compute inside shard_map is replicated on every rank; the
+collectives execute the tuned wire schedule, which is what the decision
+artifact tunes.
 """
 from __future__ import annotations
 

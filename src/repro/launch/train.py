@@ -13,8 +13,10 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import time
+from typing import Any, List
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +25,7 @@ from repro.checkpoint import save
 from repro.configs import ARCHITECTURES, CollectiveConfig, ParallelConfig
 from repro.configs.base import ShapeConfig
 from repro.data import SyntheticPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import build_train_step
 from repro.models.registry import build_model
@@ -66,7 +69,21 @@ def _write_step_trace(args, comm, params, runner, topology, step,
               f"{resid.modeled_exposed * 1e6:.0f} us modeled)")
 
 
-def main():
+@dataclasses.dataclass
+class TrainRun:
+    """What one `main` call ran: the compiled step, the mesh, the final
+    params, the last batch, and per-step host-clock numbers."""
+    compiled: Any
+    mesh: Any
+    params: Any
+    batch: Any
+    losses: List[float]
+    step_ms: List[float]
+    compile_s: float
+
+
+def main(argv=None) -> TrainRun:
+    """Train from CLI arguments (``argv``, default ``sys.argv[1:]``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m",
                     choices=sorted(ARCHITECTURES))
@@ -141,7 +158,8 @@ def main():
                          "real wall times off the critical path; "
                          "residuals need a --topology for the modeled "
                          "side")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = ARCHITECTURES[args.arch]
     if args.reduced:
@@ -260,8 +278,7 @@ def main():
     step_fn = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                       donate_argnums=donate)
 
-    api = build_model(cfg, attn_impl="xla"
-                      if jax.default_backend() != "tpu" else "auto")
+    api = build_model(cfg)
     params = jax.device_put(api.init(jax.random.PRNGKey(0)), in_sh[0])
     opt_state = jax.device_put(AdamW(lr=args.lr).init(params), in_sh[1])
     pipe = SyntheticPipeline(cfg, shape, seed=0)
@@ -288,26 +305,42 @@ def main():
         if trace_topo is None:
             print("trace: no --topology attached, writing traces "
                   "without modeled residuals")
-    t_start = time.time()
-    for i in range(args.steps):
-        batch = jax.device_put(
+
+    def batch_at(i):
+        return jax.device_put(
             {k: jnp.asarray(v) for k, v in pipe.batch_at(i).items()},
             in_sh[2])
-        t0 = time.time()
-        params, opt_state, metrics = step_fn(params, opt_state, batch)
+
+    batch = batch_at(0)
+    t0 = time.perf_counter()
+    compiled = step_fn.lower(params, opt_state, batch).compile()
+    compile_s = time.perf_counter() - t0
+    print(f"compiled train step in {compile_s:.1f}s")
+    losses, step_ms = [], []
+    t_start = time.time()
+    for i in range(args.steps):
+        if i:
+            batch = batch_at(i)
+        t0 = time.perf_counter()
+        params, opt_state, metrics = compiled(params, opt_state, batch)
         loss = float(metrics["loss"])
+        losses.append(loss)
+        step_ms.append((time.perf_counter() - t0) * 1e3)
         if i % args.log_every == 0:
-            print(f"step {i:4d} loss {loss:.4f} "
-                  f"({(time.time() - t0) * 1e3:.0f} ms)", flush=True)
+            print(f"step {i:4d} loss {loss:.4f} ({step_ms[-1]:.0f} ms)",
+                  flush=True)
         if runner is not None:
             _write_step_trace(args, comm, params, runner, trace_topo, i,
-                              wall_ms=(time.time() - t0) * 1e3)
+                              wall_ms=step_ms[-1])
     print(f"done: {args.steps} steps in {time.time() - t_start:.1f}s")
 
     if args.ckpt:
         save(args.ckpt, {"params": params, "opt": opt_state},
              step=args.steps, extra={"arch": cfg.name})
         print("checkpoint ->", args.ckpt)
+    return TrainRun(compiled=compiled, mesh=mesh, params=params,
+                    batch=batch, losses=losses, step_ms=step_ms,
+                    compile_s=compile_s)
 
 
 if __name__ == "__main__":

@@ -169,8 +169,7 @@ def moe_block(
     dispatched = xpad[gather_idx].reshape(E, C, d)           # (E, C, d)
 
     if ep_axis is not None:
-        from repro.compat import axis_size
-        tp = axis_size(ep_axis)
+        tp = jax.lax.axis_size(ep_axis)
         assert E % tp == 0, f"{E} experts not divisible by axis {tp}"
         # exchange: each rank keeps its E/tp experts, receives C slots from
         # every peer -> (E/tp, tp*C, d)

@@ -48,8 +48,7 @@ def _moe_apply(h, mp, cfg, *, ep_axis, mesh, compute_dtype,
         # values, so the two paths are bit-identical; the MoE all-to-all is
         # now a plain axis collective inside the one program, free to
         # overlap expert compute.
-        from repro import compat
-        tp = compat.axis_size(ep_axis)
+        tp = jax.lax.axis_size(ep_axis)
         S = h.shape[1]
         assert S % tp == 0, \
             f"seq {S} not divisible by expert-parallel axis {tp}"

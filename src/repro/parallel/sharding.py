@@ -14,6 +14,7 @@ from typing import Tuple
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import compat
 from repro.configs.base import ModelConfig, ParallelConfig, ShapeConfig
 
 
@@ -114,7 +115,7 @@ def ep_param_specs(params_tree, ep_axis: str):
     their storage sharding (`param_specs`' mdl(E) rule), so entering the
     manual region moves no bytes — everything else replicated (the
     attention/embedding compute is replicated over the model ranks
-    inside manual, exactly like the 0.4.x full-manual fallback)."""
+    inside manual)."""
     def rule(path, leaf):
         last = _path_str(path).rsplit("/", 1)[-1]
         if ep_axis and last in ("w_gate", "w_up", "w_down") \
@@ -189,33 +190,54 @@ def current_mesh():
     return _CURRENT_MESH
 
 
-#: trace-time override for JAX versions without ``get_abstract_mesh``
-#: (0.4.x): compat.shard_map registers its manual axes here while tracing
-_MANUAL_OVERRIDE: set = set()
-
-
-def set_manual_override(axes):
-    """Declare mesh axes as under manual shard_map control (legacy JAX).
-    Returns the previous value for restore."""
-    global _MANUAL_OVERRIDE
-    prev = _MANUAL_OVERRIDE
-    _MANUAL_OVERRIDE = set(axes)
-    return prev
-
-
 def _manual_axes():
     """Axis names currently under shard_map manual control (partial-manual
     regions): constraints must not mention them — those dims are already
     local there."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return set(_MANUAL_OVERRIDE), None
+    am = jax.sharding.get_abstract_mesh()
     if am is None or not am.axis_names:
-        return set(_MANUAL_OVERRIDE), None
+        return set(), None
     manual = {n for n, t in zip(am.axis_names, am.axis_types)
-              if "Manual" in str(t)}
+              if t == jax.sharding.AxisType.Manual}
     return manual, am
+
+
+def per_shard(f, *args, dims):
+    """``f(*args)`` run on each device's shard: a shard_map over the
+    current mesh's axes that are not manual already. The compiler cannot
+    partition a Pallas kernel, so kernel calls go through here. ``dims``
+    gives, per argument, each dimension's role: "batch" (split over the
+    data axes), "heads" (split over "model") or None (whole on every
+    device); a role is split only where every dim that has it divides.
+    The output takes the first argument's layout. With no mesh, or inside
+    a fully manual region, ``f`` runs as it is."""
+    mesh = _CURRENT_MESH
+    if mesh is None:
+        return f(*args)
+    manual, am = _manual_axes()
+    auto = tuple(a for a in mesh.axis_names if a not in manual)
+    if not auto:
+        return f(*args)
+    data = tuple(a for a in dp_axes(mesh) if a in auto)
+    dsz = 1
+    for a in data:
+        dsz *= mesh.shape[a]
+    tp = mesh.shape["model"] if "model" in auto else 1
+
+    def sizes(role):
+        return [x.shape[i] for x, ds in zip(args, dims)
+                for i, r in enumerate(ds) if r == role]
+
+    split = {
+        "batch": data if dsz > 1 and all(_div(n, dsz)
+                                         for n in sizes("batch")) else None,
+        "heads": "model" if tp > 1 and all(_div(n, tp)
+                                           for n in sizes("heads")) else None,
+        None: None,
+    }
+    specs = tuple(P(*(split[r] for r in ds)) for ds in dims)
+    return compat.shard_map(f, mesh=am if manual else mesh, in_specs=specs,
+                            out_specs=specs[0], axis_names=set(auto))(*args)
 
 
 def _constrain(x, entries):
@@ -233,10 +255,6 @@ def _constrain(x, entries):
         return None if e in manual else e
 
     entries = tuple(filt(e) for e in entries)
-    if manual and am is None:
-        # legacy JAX inside (full-)manual shard_map: no abstract mesh to
-        # constrain against; the surviving entries are hints only — drop them
-        return x
     target = am if manual else mesh
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(target, P(*entries)))

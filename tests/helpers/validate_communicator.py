@@ -498,7 +498,7 @@ def manual_grads(params, batch):
     def inner(p, b):
         _, g = jax.value_and_grad(
             lambda pp, bb: api_man.loss(pp, bb)[0])(p, b)
-        tp = compat.axis_size("model")
+        tp = jax.lax.axis_size("model")
         especs = sh.ep_param_specs(p, "model")
         g = jax.tree.map(
             lambda gg, s: gg / tp if s != P()

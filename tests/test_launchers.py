@@ -11,9 +11,10 @@ HERE = os.path.dirname(__file__)
 SRC = os.path.join(HERE, "..", "src")
 
 
-def _run(args, timeout=900, xla_devices=None):
+def _run(args, timeout=900, xla_devices=None, env_extra=None):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
+    env.update(env_extra or {})
     if xla_devices:
         env["XLA_FLAGS"] = \
             f"--xla_force_host_platform_device_count={xla_devices}"
@@ -25,12 +26,50 @@ def _run(args, timeout=900, xla_devices=None):
 
 
 def test_train_cli_single_device(tmp_path):
+    cache = tmp_path / "jax_cache"
     r = _run(["repro.launch.train", "--arch", "smollm-135m", "--reduced",
               "--steps", "4", "--seq", "64", "--batch", "2",
-              "--ckpt", str(tmp_path / "ck")])
+              "--ckpt", str(tmp_path / "ck")],
+             env_extra={"JAX_COMPILATION_CACHE_DIR": str(cache),
+                        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"})
     assert r.returncode == 0, r.stdout + r.stderr
     assert "step    3" in r.stdout
     assert (tmp_path / "ck" / "manifest.json").exists()
+    # the environment's cache directory is the one the launcher fills
+    assert cache.is_dir() and any(cache.iterdir())
+
+
+def test_compile_cache_dir_from_env(monkeypatch, tmp_path):
+    import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before  # set nothing
+
+
+def test_compile_cache_dir_defaults_to_checkout(monkeypatch):
+    import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.normpath(os.path.join(HERE, "..", ".jax_cache"))
+    try:
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_chip_smoke_refuses_cpu():
+    """Without a TPU the smoke script fails and prints no result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, os.path.join(HERE, "..",
+                                                     "chip_smoke.py")],
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode != 0
+    assert '"ok": true' not in r.stdout
+    assert "needs a TPU" in r.stderr
 
 
 def test_train_cli_tuned_collective_8dev():
