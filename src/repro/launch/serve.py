@@ -56,7 +56,7 @@ def _prefill_extra_fn(cfg):
 
 
 def _serve_continuous(args, cfg, api, params, comm, mesh):
-    from repro.obs import export as obs_export
+    from repro.obs import MetricsRegistry, export as obs_export
     from repro.serve import ServeEngine, Scheduler, load_trace, \
         synthetic_trace
 
@@ -98,9 +98,12 @@ def _serve_continuous(args, cfg, api, params, comm, mesh):
     if args.trace_dir:
         import os
         os.makedirs(args.trace_dir, exist_ok=True)
+        counters = MetricsRegistry().merge(res.counters)
+        if comm is not None:
+            counters.merge(comm.metrics)
         obs_export.write_summary(
             os.path.join(args.trace_dir, "decode_summary.json"),
-            counters=comm.metrics if comm is not None else None,
+            counters=counters,
             extra={"arch": cfg.name, "mode": "continuous",
                    "tensor_parallel": args.tensor_parallel,
                    "max_active": args.max_active, "block_size": bs,
@@ -161,7 +164,9 @@ def main(argv=None):
     ap.add_argument("--trace-dir", default=None,
                     help="write decode_summary.json here (per-token "
                          "latency percentiles + throughput + config; "
-                         "with --continuous also per-request spans)")
+                         "with --continuous also per-request records and "
+                         "the engine run's counters: admissions, decode "
+                         "steps, garbage collections and their seconds)")
     args = ap.parse_args(argv)
     enable_compile_cache()
     # serving places its arrays itself; a training mesh left by an earlier

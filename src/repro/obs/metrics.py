@@ -2,7 +2,8 @@
 
 A `MetricsRegistry` is a flat ``(name, label) -> float`` accumulator —
 bytes moved per tier, collectives issued per algorithm, decision-cache
-hits and misses. It is deliberately dumb: no locks, no histograms, no
+hits and misses, a serving run's admissions and garbage-collection
+pauses. It is deliberately dumb: no locks, no histograms, no
 export protocol — the counters exist so a launch (or a test) can ask
 "how many table lookups did this step trace actually perform" without
 instrumenting call sites by hand. `repro.comms.report.render_metrics`
@@ -24,6 +25,15 @@ class MetricsRegistry:
     def inc(self, name: str, value: float = 1, *, label: str = "") -> None:
         key = (name, str(label))
         self._counts[key] = self._counts.get(key, 0.0) + float(value)
+
+    def observe_max(self, name: str, value: float, *,
+                    label: str = "") -> None:
+        """Keep the largest ``value`` seen under ``name`` (a longest
+        pause, say) instead of a sum. `merge` adds values, so merge a
+        maximum only into a registry that does not hold the same name."""
+        key = (name, str(label))
+        self._counts[key] = max(self._counts.get(key, float(value)),
+                                float(value))
 
     def get(self, name: str, *, label: str = "") -> float:
         return self._counts.get((name, str(label)), 0.0)

@@ -29,14 +29,38 @@ objects ``Communicator.explain`` renders, so the reported decode plan is
 exactly the executed plan. Decode logits at serving batch sizes are
 KB-scale messages: the small-message end of the tuning grid.
 
-Timing is injected: with ``cost_model=None`` the run loop uses the wall
-clock; a ``cost_model(kind, n) -> seconds`` callable switches every
-duration (and the arrival clock) to deterministic simulated time, which
-is what the serving benchmark gates on.
+The run loop's clock: with ``cost_model=None`` it is the wall clock; a
+``cost_model(kind, n) -> seconds`` callable switches every duration (and
+the arrival clock) to deterministic simulated time, which is what the
+simulated serving matrix (``benchmarks/serving.py``) gates on.
+
+Spans and counters: the run loop and the calls it makes record host
+spans with `repro.obs.span`, on the clock of a running ``jax.profiler``
+session (and nothing without one), so a device trace shows what the
+host was doing in each idle gap:
+
+  serve.run (max_active)
+    serve.wait              sleeping for the next arrival, nothing active
+    serve.schedule          admission policy, retire and release
+    serve.admit (rid, prompt_len, slot)
+      serve.prefill         prompt upload and the jitted prefill
+      serve.admit.state     the slot's state, length and first token
+      serve.admit.first_token   reading the first token back
+    serve.step (active)     one decode step, tokens handed to the scheduler
+      serve.step.dispatch   the jitted step and the token update
+      serve.step.readback   reading the step's tokens back
+    serve.gc (generation)   a Python garbage collection, wherever it falls
+
+The simulated clock records the same spans. Each run also returns its
+counters (`ServeResult.counters`): ``admissions``, ``decode_steps``,
+``gc_collections`` by generation, ``gc_s`` and ``gc_max_s`` (the
+longest single collection), with or without a profiler.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import gc
 import time
 from typing import Callable, Optional
 
@@ -44,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import MetricsRegistry, span
 from repro.serve.paged_kv import PagedKV, gather_views, scatter_tokens
 
 PAGED_LEAVES = ("k", "v")
@@ -51,10 +76,39 @@ PAGED_LEAVES = ("k", "v")
 
 @dataclasses.dataclass
 class ServeResult:
-    """Outcome of a serving run: aggregate latency/throughput + spans."""
+    """Outcome of a serving run: aggregate latency/throughput, per-request
+    records and the run's counters."""
     summary: dict
     records: list
     wall_s: float
+    counters: MetricsRegistry
+
+
+@contextlib.contextmanager
+def _gc_spans(counters: MetricsRegistry):
+    """While open, span each Python garbage collection as ``serve.gc``
+    and count it into ``counters``. The ``gc.callbacks`` entry cannot
+    raise: an exception there would be printed and swallowed."""
+    state = {}
+
+    def callback(phase, info):
+        if phase == "start":
+            ann = span("serve.gc", generation=info["generation"])
+            ann.__enter__()
+            state["open"] = (ann, time.perf_counter())
+        elif "open" in state:
+            ann, t0 = state.pop("open")
+            dt = time.perf_counter() - t0
+            ann.__exit__(None, None, None)
+            counters.inc("gc_collections", label=str(info["generation"]))
+            counters.inc("gc_s", dt)
+            counters.observe_max("gc_max_s", dt)
+
+    gc.callbacks.append(callback)
+    try:
+        yield
+    finally:
+        gc.callbacks.remove(callback)
 
 
 class ServeEngine:
@@ -189,19 +243,21 @@ class ServeEngine:
         if self.paged is not None and not self.paged.admit(slot):
             raise RuntimeError("KV block pool exhausted")
         self._free_slots.pop()
-        tokens = jnp.asarray(np.asarray(req.prompt, np.int32))[None]
-        logits, cache = self._prefill(self.params, tokens,
-                                      **self.prefill_extra(req))
-        if self.paged is not None:
-            self.paged.write_view(slot, {n: cache[n]
-                                         for n in self.paged_names})
-        opq = {n: v for n, v in cache.items()
-               if n not in self.paged_names and n != "length"}
-        self.opaque = jax.tree.map(lambda st, leaf: st.at[slot].set(leaf),
-                                   self.opaque, opq)
-        self.lengths = self.lengths.at[slot].set(req.prompt_len)
-        tok0 = jnp.argmax(logits[0, -1]).astype(jnp.int32)
-        self.cur_tokens = self.cur_tokens.at[slot].set(tok0)
+        with span("serve.prefill"):
+            tokens = jnp.asarray(np.asarray(req.prompt, np.int32))[None]
+            logits, cache = self._prefill(self.params, tokens,
+                                          **self.prefill_extra(req))
+        with span("serve.admit.state"):
+            if self.paged is not None:
+                self.paged.write_view(slot, {n: cache[n]
+                                             for n in self.paged_names})
+            opq = {n: v for n, v in cache.items()
+                   if n not in self.paged_names and n != "length"}
+            self.opaque = jax.tree.map(
+                lambda st, leaf: st.at[slot].set(leaf), self.opaque, opq)
+            self.lengths = self.lengths.at[slot].set(req.prompt_len)
+            tok0 = jnp.argmax(logits[0, -1]).astype(jnp.int32)
+            self.cur_tokens = self.cur_tokens.at[slot].set(tok0)
         self._active_mask[slot] = True
         self._slot_req[slot] = req
         return slot
@@ -216,19 +272,21 @@ class ServeEngine:
 
     def step(self):
         """One decode step for every active slot. Returns {slot: token}."""
-        tables = (self.paged.tables if self.paged is not None
-                  else jnp.zeros((self.max_active, 1), jnp.int32))
-        pools = self.paged.pools if self.paged is not None else {}
-        active = jnp.asarray(self._active_mask)
-        logits, next_tok, new_pools, new_opq, new_lens = self._step(
-            self.params, pools, tables, self.opaque, self.lengths,
-            self.cur_tokens, active)
-        if self.paged is not None:
-            self.paged.pools = new_pools
-        self.opaque = new_opq
-        self.lengths = new_lens
-        self.cur_tokens = jnp.where(active, next_tok, self.cur_tokens)
-        toks = np.asarray(next_tok)      # sync point: honest token latency
+        with span("serve.step.dispatch"):
+            tables = (self.paged.tables if self.paged is not None
+                      else jnp.zeros((self.max_active, 1), jnp.int32))
+            pools = self.paged.pools if self.paged is not None else {}
+            active = jnp.asarray(self._active_mask)
+            logits, next_tok, new_pools, new_opq, new_lens = self._step(
+                self.params, pools, tables, self.opaque, self.lengths,
+                self.cur_tokens, active)
+            if self.paged is not None:
+                self.paged.pools = new_pools
+            self.opaque = new_opq
+            self.lengths = new_lens
+            self.cur_tokens = jnp.where(active, next_tok, self.cur_tokens)
+        with span("serve.step.readback"):
+            toks = np.asarray(next_tok)  # sync point: honest token latency
         return {s: int(toks[s]) for s in range(self.max_active)
                 if self._active_mask[s]}
 
@@ -240,67 +298,87 @@ class ServeEngine:
 
         ``cost_model(kind, n) -> seconds`` (kinds: ``"prefill"`` with the
         prompt length, ``"decode"`` with the active count) switches the
-        run to deterministic simulated time; otherwise wall clock.
+        run to deterministic simulated time; otherwise wall clock. A
+        request's ``admit_s`` is taken before its prefill, so its queue
+        wait excludes the prefill.
         """
-        sim = cost_model is not None
-        wall0 = time.perf_counter()
-        now = 0.0 if sim else time.perf_counter()
+        counters = MetricsRegistry()
+        with _gc_spans(counters), span("serve.run",
+                                       max_active=self.max_active):
+            sim = cost_model is not None
+            wall0 = time.perf_counter()
+            now = 0.0 if sim else time.perf_counter()
 
-        def idle_until(t):
-            nonlocal now
-            if sim:
-                now = max(now, t)
-            else:
-                wait = t - time.perf_counter()
-                if wait > 0:
-                    time.sleep(wait)
-                now = time.perf_counter()
-
-        if not sim:
-            # express trace arrivals relative to run start
-            base = now
-            for r in list(sched.pending):
-                r.arrival_s += base
-
-        steps = 0
-        while not sched.done and steps < max_steps:
-            steps += 1
-            if not sched.active:
-                nxt = sched.next_arrival()
-                if nxt is not None and nxt > now:
-                    idle_until(nxt)
-            for req in sched.admissible(now):
-                t0 = now if sim else time.perf_counter()
-                slot = self.admit(req)
+            def idle_until(t):
+                nonlocal now
                 if sim:
-                    now += cost_model("prefill", req.prompt_len)
-                    dur_ms = 1e3 * cost_model("prefill", req.prompt_len)
+                    now = max(now, t)
                 else:
+                    wait = t - time.perf_counter()
+                    if wait > 0:
+                        time.sleep(wait)
                     now = time.perf_counter()
-                    dur_ms = 1e3 * (now - t0)
-                sched.start(req, now, slot)
-                sched.note_prefill(dur_ms)
-                # first token is produced by the prefill itself
-                tok0 = int(np.asarray(self.cur_tokens)[slot])
-                sched.record_token(req, tok0, now)
-            if sched.active:
-                toks = self.step()
-                if sim:
-                    now += cost_model("decode", len(toks))
-                else:
-                    now = time.perf_counter()
-                for slot, tok in toks.items():
-                    req = self._slot_req.get(slot)
-                    if req is not None and len(req.generated) < req.max_new:
-                        sched.record_token(req, tok, now)
-                sched.note_decode(now)
-            for req in sched.retire_done(now):
-                self.release(req.slot)
 
-        assert sched.done, f"serving loop hit max_steps={max_steps}"
-        summary = sched.latency_summary()
-        return ServeResult(
-            summary=summary,
-            records=[r.record() for r in
-                     sorted(sched.finished, key=lambda r: r.rid)],
-            wall_s=time.perf_counter() - wall0)
+            if not sim:
+                # express trace arrivals relative to run start
+                base = now
+                for r in list(sched.pending):
+                    r.arrival_s += base
+
+            steps = 0
+            while not sched.done and steps < max_steps:
+                steps += 1
+                if not sched.active:
+                    nxt = sched.next_arrival()
+                    if nxt is not None and nxt > now:
+                        with span("serve.wait"):
+                            idle_until(nxt)
+                with span("serve.schedule"):
+                    admitted = sched.admissible(now)
+                for req in admitted:
+                    with span("serve.admit", rid=req.rid,
+                              prompt_len=req.prompt_len) as ann:
+                        t0 = now if sim else time.perf_counter()
+                        slot = self.admit(req)
+                        ann.set_metadata(slot=slot)
+                        if sim:
+                            dur_s = cost_model("prefill", req.prompt_len)
+                            now += dur_s
+                        else:
+                            now = time.perf_counter()
+                            dur_s = now - t0
+                        sched.start(req, t0, slot)
+                        sched.note_prefill(1e3 * dur_s)
+                        # first token is produced by the prefill itself
+                        with span("serve.admit.first_token"):
+                            tok0 = int(np.asarray(self.cur_tokens)[slot])
+                        sched.record_token(req, tok0, now)
+                        counters.inc("admissions")
+                stepped = bool(sched.active)
+                if stepped:
+                    with span("serve.step", active=len(sched.active)):
+                        toks = self.step()
+                        if sim:
+                            now += cost_model("decode", len(toks))
+                        else:
+                            now = time.perf_counter()
+                        for slot, tok in toks.items():
+                            req = self._slot_req.get(slot)
+                            if (req is not None
+                                    and len(req.generated) < req.max_new):
+                                sched.record_token(req, tok, now)
+                        counters.inc("decode_steps")
+                with span("serve.schedule"):
+                    if stepped:
+                        sched.note_decode(now)
+                    for req in sched.retire_done(now):
+                        self.release(req.slot)
+
+            assert sched.done, f"serving loop hit max_steps={max_steps}"
+            summary = sched.latency_summary()
+            return ServeResult(
+                summary=summary,
+                records=[r.record() for r in
+                         sorted(sched.finished, key=lambda r: r.rid)],
+                wall_s=time.perf_counter() - wall0, counters=counters)
+

@@ -220,11 +220,13 @@ class Scheduler:
     # -- reporting ---------------------------------------------------------
 
     def latency_summary(self):
-        """Inter-token latency percentiles (ms) + throughput over the run."""
+        """Inter-token latency percentiles (ms) + throughput over the run.
+        The gaps lie between a request's output tokens; the wait for its
+        first token (queue and prefill) is not one of them."""
         gaps = []
         for r in self.finished:
-            ts = ([r.admit_s] + r.token_s) if r.token_s else []
-            gaps.extend(1e3 * (b - a) for a, b in zip(ts, ts[1:]))
+            gaps.extend(1e3 * (b - a) for a, b in zip(r.token_s,
+                                                      r.token_s[1:]))
         toks = sum(len(r.generated) for r in self.finished)
         t0 = min((r.arrival_s for r in self.finished), default=0.0)
         t1 = max((r.finish_s for r in self.finished), default=0.0)

@@ -131,6 +131,9 @@ def test_serve_cli_continuous(tmp_path):
     for rec in doc["requests"]:
         assert rec["new_tokens"] == 6
         assert rec["ttft_ms"] >= 0.0 and rec["finish_s"] >= rec["admit_s"]
+    # the engine run's counters, for an operator without a profiler
+    assert doc["counters"]["admissions"] == 6
+    assert doc["counters"]["decode_steps"] >= 5
 
 
 def test_serve_cli_continuous_tp_slo_8dev(tmp_path):

@@ -349,6 +349,18 @@ def test_metrics_registry():
     assert "hits{plan} = 6" in text
 
 
+def test_metrics_registry_observe_max():
+    reg = MetricsRegistry()
+    reg.observe_max("pause_s", 0.2)
+    reg.observe_max("pause_s", 0.05)
+    reg.observe_max("pause_s", 0.3, label="gen2")
+    reg.observe_max("pause_s", 0.1, label="gen2")
+    assert reg.get("pause_s") == 0.2
+    assert reg.get("pause_s", label="gen2") == 0.3
+    reg.observe_max("negative", -1.0)
+    assert reg.get("negative") == -1.0      # first value, not max(0, v)
+
+
 def test_decision_cache_counters_on_200_leaf_tree(fake_collectives):
     # no bucketing: each of the 200 leaves resolves its own per-level
     # specs, so the cache does real work leaf-over-leaf

@@ -11,6 +11,8 @@ the token sequences of running each request alone through the family's
 covered as their own cases; the tuned tensor-parallel path runs in a
 2-device subprocess against the committed decision artifact.
 """
+import gc
+import glob
 import os
 import subprocess
 import sys
@@ -168,6 +170,21 @@ def test_scheduler_preempt_recompute():
     assert back.prompt == (0, 1, 2, 3, 7, 8, 9)     # generated folded in
     assert back.max_new == 3 and back.generated == []
     assert sched.next_arrival() == 0.0              # head of the queue
+
+
+def test_scheduler_latency_gaps_are_between_output_tokens():
+    """Inter-token gaps start at the first output token: the wait for it
+    (queue and prefill) is time to first token, not an inter-token gap."""
+    r0 = _req(0, 0.0, new=3)
+    sched = Scheduler([r0], max_active=1, token_budget=100)
+    (got,) = sched.admissible(0.0)
+    sched.start(got, 0.0, 0)
+    for t, tok in ((0.5, 7), (0.6, 8), (0.7, 9)):
+        sched.record_token(r0, tok, t)
+    sched.retire_done(0.7)
+    s = sched.latency_summary()
+    assert s["token_ms_p50"] == pytest.approx(100.0)
+    assert s["token_ms_p99"] == pytest.approx(100.0)
 
 
 # ---------------------------------------------------------------------------
@@ -398,3 +415,132 @@ def test_engine_tp_tuned_bit_identical_2dev():
     assert r.returncode == 0, \
         f"STDOUT:\n{r.stdout[-4000:]}\nERR:\n{r.stderr[-2000:]}"
     assert "FAILS: 0" in r.stdout
+
+
+# ---------------------------------------------------------------------------
+# engine spans on the profiler's clock, and the run's counters
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def tiny_engine():
+    """One reduced dense engine shared by the instrumentation tests (each
+    run retires every request, so the engine is free again after it)."""
+    cfg = ARCHITECTURES["smollm-135m"].reduced()
+    api = build_model(cfg, attn_impl="xla")
+    params = api.init(jax.random.PRNGKey(0))
+    return ServeEngine(api, params, max_active=2, view_len=12,
+                       block_size=BLOCK), cfg
+
+
+def _tiny_trace(vocab, n=3):
+    return synthetic_trace(n, rate_rps=500.0, vocab=vocab,
+                           prompt_lens=(4,), max_new=4, seed=0)
+
+
+class _Costs:
+    """A simulated clock that counts the engine's prefills and decode
+    steps, and can run a hook inside the run loop."""
+
+    def __init__(self, hook=None):
+        self.calls = {"prefill": 0, "decode": 0}
+        self.hook = hook
+
+    def __call__(self, kind, n):
+        self.calls[kind] += 1
+        if self.hook:
+            self.hook(kind)
+        return 1e-3
+
+
+def _host_spans(trace_dir):
+    """Every ``serve.*`` host event of the newest profile under
+    ``trace_dir``: (thread line, name, start ns, end ns, stats)."""
+    from jax.profiler import ProfileData
+    files = sorted(glob.glob(os.path.join(str(trace_dir), "**",
+                                          "*.xplane.pb"), recursive=True),
+                   key=os.path.getmtime)
+    pd = ProfileData.from_file(files[-1])
+    return [(line.name, e.name, e.start_ns, e.end_ns, dict(e.stats))
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("serve.")]
+
+
+def test_engine_spans_nest_on_profiler_clock(tiny_engine, tmp_path):
+    engine, cfg = tiny_engine
+    plain = Scheduler(_tiny_trace(cfg.vocab_size), max_active=2,
+                      token_budget=24)
+    engine.run(plain, cost_model=_Costs())
+    traced = Scheduler(_tiny_trace(cfg.vocab_size), max_active=2,
+                       token_budget=24)
+    with jax.profiler.trace(str(tmp_path)):
+        engine.run(traced, cost_model=_Costs())
+    # the profiler changes no token
+    assert ({r.rid: r.generated for r in traced.finished}
+            == {r.rid: r.generated for r in plain.finished})
+
+    spans = _host_spans(tmp_path)
+    (run,) = [s for s in spans if s[1] == "serve.run"]
+    assert run[4] == {"max_active": 2}
+    assert all(run[2] <= s[2] and s[3] <= run[3] for s in spans)
+
+    def inside(parent, name):
+        return [s for s in spans if s[1] == name and s[0] == parent[0]
+                and parent[2] <= s[2] and s[3] <= parent[3]]
+
+    admits = [s for s in spans if s[1] == "serve.admit"]
+    assert sorted(s[4]["rid"] for s in admits) == [0, 1, 2]
+    for a in admits:
+        assert a[4]["prompt_len"] == 4 and a[4]["slot"] in (0, 1)
+        for child in ("serve.prefill", "serve.admit.state",
+                      "serve.admit.first_token"):
+            assert len(inside(a, child)) == 1, (a, child)
+    steps = [s for s in spans if s[1] == "serve.step"]
+    assert steps and all(1 <= s[4]["active"] <= 2 for s in steps)
+    for st in steps:
+        for child in ("serve.step.dispatch", "serve.step.readback"):
+            assert len(inside(st, child)) == 1, (st, child)
+    assert any(s[1] == "serve.schedule" for s in spans)
+
+
+def test_engine_run_counters_and_queue_wait(tiny_engine):
+    engine, cfg = tiny_engine
+    trace = _tiny_trace(cfg.vocab_size)
+    costs = _Costs()
+    res = engine.run(Scheduler(trace, max_active=2, token_budget=24),
+                     cost_model=costs)
+    c = res.counters
+    assert c.get("admissions") == len(trace) == costs.calls["prefill"]
+    assert c.get("decode_steps") == costs.calls["decode"] > 0
+    # the first request meets an idle engine: admitted on arrival, its
+    # first token one prefill later
+    first = min(trace, key=lambda r: r.arrival_s)
+    assert first.admit_s == first.arrival_s
+    assert first.first_token_s == pytest.approx(first.arrival_s + 1e-3)
+    rec = {r["rid"]: r for r in res.records}[first.rid]
+    assert rec["queue_ms"] == 0.0
+
+
+def test_engine_run_counts_gc_and_unhooks(tiny_engine):
+    engine, cfg = tiny_engine
+    before = list(gc.callbacks)
+    forced = []
+
+    def collect(kind):
+        if kind == "decode" and not forced:
+            forced.append(gc.collect())
+
+    res = engine.run(Scheduler(_tiny_trace(cfg.vocab_size), max_active=2,
+                               token_budget=24),
+                     cost_model=_Costs(collect))
+    c = res.counters
+    assert forced and c.get("gc_collections", label="2") >= 1
+    assert 0 < c.get("gc_max_s") <= c.get("gc_s")
+    assert gc.callbacks == before
+
+    def fail(kind):
+        raise RuntimeError("clock failed")
+
+    with pytest.raises(RuntimeError, match="clock failed"):
+        engine.run(Scheduler(_tiny_trace(cfg.vocab_size), max_active=2,
+                             token_budget=24), cost_model=_Costs(fail))
+    assert gc.callbacks == before
