@@ -44,6 +44,22 @@ def _group_slices(params_layers, cfg: ModelConfig):
             for g in range(ng)]
 
 
+def _join_layers(parts):
+    """``jnp.concatenate(parts)`` on the layer axis, as a sum of the parts
+    each padded with -0.0 to the whole stack (x + -0.0 is x exactly).
+    Under ``vmap`` a pad and an add keep a batched axis where it is,
+    where a concatenate moves it to the front; so a serving engine that
+    stores its slots on the cache's batch axis updates the SSM state
+    without transposing all of it."""
+    n, lo, out = sum(p.shape[0] for p in parts), 0, None
+    for p in parts:
+        pad = [(lo, n - lo - p.shape[0], 0)] + [(0, 0, 0)] * (p.ndim - 1)
+        lo += p.shape[0]
+        p = jax.lax.pad(p, jnp.array(-0.0, p.dtype), pad)
+        out = p if out is None else out + p
+    return out
+
+
 def _shared_attn(x, sp, cfg, positions, *, window, kv, compute_dtype,
                  attn_impl, return_kv=False):
     h = L.rms_norm(x, sp["ln1"], cfg.norm_eps)
@@ -121,8 +137,10 @@ def decode_step(params, cache, tokens, cfg: ModelConfig, *, window=0,
 
     new_conv, new_ssd, new_k, new_v = [], [], [], []
     for g, grp in enumerate(_group_slices(params["layers"], cfg)):
-        conv = jax.lax.dynamic_slice_in_dim(cache["ssm"]["conv"], g * ae, ae)
-        ssd_st = jax.lax.dynamic_slice_in_dim(cache["ssm"]["ssd"], g * ae, ae)
+        conv = jax.lax.slice_in_dim(cache["ssm"]["conv"], g * ae,
+                                    (g + 1) * ae)
+        ssd_st = jax.lax.slice_in_dim(cache["ssm"]["ssd"], g * ae,
+                                      (g + 1) * ae)
         x, (nc, ns) = L.layer_scan(mamba_body, x, (grp, conv, ssd_st),
                                    unroll=unroll)
         kv = {"k": cache["k"][g], "v": cache["v"][g], "length": length}
@@ -136,8 +154,8 @@ def decode_step(params, cache, tokens, cfg: ModelConfig, *, window=0,
 
     logits = T.logits_fn(params, x, cfg, compute_dtype)[:, 0]
     new_cache = {
-        "ssm": {"conv": jnp.concatenate(new_conv),
-                "ssd": jnp.concatenate(new_ssd)},
+        "ssm": {"conv": _join_layers(new_conv),
+                "ssd": _join_layers(new_ssd)},
         "k": jnp.stack(new_k),
         "v": jnp.stack(new_v),
         "length": length + 1,

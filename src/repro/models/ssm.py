@@ -83,7 +83,7 @@ def ssm_block(
     *,
     compute_dtype=jnp.bfloat16,
     ssd_impl: str = "auto",
-    state=None,                   # decode: {"conv": (B,W-1,Cd), "ssd": (B,H,N,P)}
+    state=None,                   # decode: {"conv": (B,W-1,Cd), "ssd": (B,H,P,N)}
     return_state: bool = False,   # prefill: sequence mode + final decode state
 ):
     """Returns (out, new_state) — new_state None unless ``state`` given or
@@ -125,7 +125,7 @@ def ssm_block(
             cum = jnp.cumsum(dt, axis=1)                       # (B,S,H)
             decay = jnp.exp((cum[:, -1:] - cum) * A[None, None, :])
             ssd_state = jnp.einsum(
-                "bsh,bsn,bshp->bhnp", dt * decay,
+                "bsh,bsn,bshp->bhpn", dt * decay,
                 Bm.astype(jnp.float32), xs.astype(jnp.float32))
             new_state = {"conv": conv_state, "ssd": ssd_state}
     else:
@@ -136,10 +136,10 @@ def ssm_block(
         Cm = xBC[:, 0, di + N:]
         dt0 = dt[:, 0]                                       # (B,H)
         a = jnp.exp(dt0 * A[None, :])                        # (B,H)
-        upd = jnp.einsum("bh,bn,bhp->bhnp", dt0, Bm.astype(jnp.float32),
+        upd = jnp.einsum("bh,bn,bhp->bhpn", dt0, Bm.astype(jnp.float32),
                          xs.astype(jnp.float32))
         ssd_state = state["ssd"] * a[..., None, None] + upd
-        y = jnp.einsum("bn,bhnp->bhp", Cm.astype(jnp.float32), ssd_state)
+        y = jnp.einsum("bn,bhpn->bhp", Cm.astype(jnp.float32), ssd_state)
         y = y + xs.astype(jnp.float32) * p["D"].astype(jnp.float32)[None, :, None]
         y = y.reshape(B_, 1, di).astype(cd)
         new_state = {"conv": conv_state, "ssd": ssd_state}
@@ -153,11 +153,15 @@ def ssm_block(
 
 def init_ssm_state(cfg: ModelConfig, batch: int, layers: int,
                    dtype=jnp.float32):
+    """Per layer: the conv history and the SSD state, ``(heads, head_dim,
+    state)`` with the state size (128 in mamba2) last: a TPU tiles it
+    without padding and updates and reads it in that layout, where a
+    head-dim-last state is relaid out twice per layer each decode step."""
     return {
         "conv": jnp.zeros((layers, batch, cfg.d_conv - 1, conv_dim(cfg)),
                           jnp.bfloat16),
-        "ssd": jnp.zeros((layers, batch, cfg.ssm_heads, cfg.ssm_state,
-                          cfg.ssm_head_dim), dtype),
+        "ssd": jnp.zeros((layers, batch, cfg.ssm_heads, cfg.ssm_head_dim,
+                          cfg.ssm_state), dtype),
     }
 
 

@@ -10,16 +10,29 @@ family cache from ``api.init_cache(batch=1, view_len)`` is split into:
     a :class:`~repro.serve.paged_kv.PagedKV` block pool and materialized
     per step as dense per-request views through the block tables;
   * opaque per-request state — everything else (SSM conv/ssd state,
-    enc-dec cross KV, ...), stacked along a leading slot axis;
+    enc-dec cross KV, ...), with the slots stacked on the cache's own
+    batch axis (found per leaf by comparing the cache's shapes at batch
+    1 and 2; a leaf without one is stacked on a leading axis). So the
+    SSM state ``(layers, batch, ...)`` is stored ``(layers, slots, 1,
+    ...)``, and the decode step's scan over layers reads and writes it
+    as it is stored, with no transpose of the whole state;
   * lengths — one engine-owned ``(max_active,)`` vector (per-request
     scalar under vmap), replacing the cache's scalar ``length``.
 
 One jitted step gathers the views, runs ``jax.vmap(api.decode_step)``
 with batch-1 per request, scatters each request's newly written KV slot
-back into its blocks, and argmaxes the next token. Each vmap instance is
-exactly the dense single-request decode — paged serving is therefore
-bit-identical to the per-request dense oracle by construction (the
-correctness tests assert this across every registry family).
+back into its blocks, and argmaxes the next token. The scan over layers
+writes the new state layer by layer into its own output buffer, so the
+step does not donate the old one: on a TPU a donated input makes XLA
+copy the whole new state into the old buffer after the loop. Admission
+writes a request's prefilled state, length and first token into its
+slot with one more jitted program, which donates what it updates and so
+writes only that slot, in place. Neither copies the whole state.
+
+Each vmap instance is exactly the dense single-request decode — paged
+serving is therefore bit-identical to the per-request dense oracle by
+construction (the correctness tests assert this across every registry
+family).
 
 With a mesh + ``Communicator`` the whole step runs under ``shard_map``
 and the per-token logits assembly goes through the tuned collective —
@@ -72,6 +85,13 @@ from repro.obs import MetricsRegistry, span
 from repro.serve.paged_kv import PagedKV, gather_views, scatter_tokens
 
 PAGED_LEAVES = ("k", "v")
+
+
+def _batch_axis(one, two) -> int:
+    """The axis of a cache leaf whose size follows the batch, from the
+    leaf's shapes at batch 1 and 2; 0 for a leaf with no batch axis."""
+    return next((i for i, (a, b) in enumerate(zip(one.shape, two.shape))
+                 if a != b), 0)
 
 
 @dataclasses.dataclass
@@ -129,18 +149,23 @@ class ServeEngine:
         # per-request inputs beyond the token prompt (encdec: audio)
         self.prefill_extra = prefill_extra or (lambda req: {})
 
-        tmpl = api.init_cache(1, view_len)
+        def cache_shapes(batch):
+            return jax.eval_shape(lambda: api.init_cache(batch, view_len))
+
+        tmpl = cache_shapes(1)
         self._has_length = "length" in tmpl
         paged_tmpl = {n: tmpl[n] for n in PAGED_LEAVES if n in tmpl}
         self.paged_names = tuple(paged_tmpl)
         self.paged = PagedKV(paged_tmpl, block_size=block_size,
                              max_requests=max_active,
                              num_blocks=num_blocks) if paged_tmpl else None
-        opaque_tmpl = {n: v for n, v in tmpl.items()
-                       if n not in self.paged_names and n != "length"}
+        opaque_tmpl = self._opaque(tmpl)
         R = max_active
+        self._slot_axes = jax.tree.map(_batch_axis, opaque_tmpl,
+                                       self._opaque(cache_shapes(2)))
         self.opaque = jax.tree.map(
-            lambda a: jnp.zeros((R,) + a.shape, a.dtype), opaque_tmpl)
+            lambda a, ax: jnp.zeros(a.shape[:ax] + (R,) + a.shape[ax:],
+                                    a.dtype), opaque_tmpl, self._slot_axes)
         self.lengths = jnp.zeros((R,), jnp.int32)
         self.cur_tokens = jnp.zeros((R,), jnp.int32)
         self._free_slots = list(range(R - 1, -1, -1))
@@ -157,6 +182,12 @@ class ServeEngine:
             lambda params, tokens, **extra:
             self.api.prefill(params, tokens, self.view_len, **extra))
         self._step = self._build_step()
+        self._write_slot = self._build_write_slot()
+
+    def _opaque(self, cache):
+        """The cache's per-request state the engine stores per slot."""
+        return {n: v for n, v in cache.items()
+                if n not in self.paged_names and n != "length"}
 
     # -- tuned decode plan -------------------------------------------------
 
@@ -175,7 +206,7 @@ class ServeEngine:
         T, bs = self.view_len, self.block_size
         paged_names, has_length = self.paged_names, self._has_length
         tp, ax, collective = self._tp, self._axis, self._collective
-        comm = self._comm
+        comm, axes = self._comm, self._slot_axes
 
         def one(params, view, opq, ln, tok):
             cache = {**opq, **view}
@@ -189,7 +220,8 @@ class ServeEngine:
         def step(params, pools, tables, opaque, lengths, tokens, active):
             views = (gather_views(pools, tables, bs) if paged_names else {})
             logits, new_views, new_opq, new_lens = jax.vmap(
-                one, in_axes=(None, 0, 0, 0, 0))(
+                one, in_axes=(None, 0, axes, 0, 0),
+                out_axes=(0, 0, axes, 0))(
                 params, views, opaque, lengths, tokens)
             if tp:
                 from repro.launch.tp_decode import logits_request
@@ -230,6 +262,23 @@ class ServeEngine:
                 check_vma=False)
         return jax.jit(step)
 
+    def _build_write_slot(self):
+        """One program that puts an admitted request's prefilled state,
+        length and first token into its slot, in place."""
+        axes = self._slot_axes
+
+        def write(opaque, lengths, cur_tokens, slot, opq, prompt_len,
+                  last_logits):
+            opaque = jax.tree.map(
+                lambda st, leaf, ax: jax.lax.dynamic_update_slice_in_dim(
+                    st, jnp.expand_dims(leaf.astype(st.dtype), ax), slot, ax),
+                opaque, opq, axes)
+            lengths = lengths.at[slot].set(prompt_len)
+            tok0 = jnp.argmax(last_logits).astype(jnp.int32)
+            return opaque, lengths, cur_tokens.at[slot].set(tok0)
+
+        return jax.jit(write, donate_argnums=(0, 1, 2))
+
     # -- request lifecycle -------------------------------------------------
 
     def admit(self, req) -> int:
@@ -251,13 +300,9 @@ class ServeEngine:
             if self.paged is not None:
                 self.paged.write_view(slot, {n: cache[n]
                                              for n in self.paged_names})
-            opq = {n: v for n, v in cache.items()
-                   if n not in self.paged_names and n != "length"}
-            self.opaque = jax.tree.map(
-                lambda st, leaf: st.at[slot].set(leaf), self.opaque, opq)
-            self.lengths = self.lengths.at[slot].set(req.prompt_len)
-            tok0 = jnp.argmax(logits[0, -1]).astype(jnp.int32)
-            self.cur_tokens = self.cur_tokens.at[slot].set(tok0)
+            self.opaque, self.lengths, self.cur_tokens = self._write_slot(
+                self.opaque, self.lengths, self.cur_tokens, slot,
+                self._opaque(cache), req.prompt_len, logits[0, -1])
         self._active_mask[slot] = True
         self._slot_req[slot] = req
         return slot

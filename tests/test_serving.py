@@ -400,6 +400,105 @@ def test_engine_preempt_release_readmit_matches_uninterrupted():
     assert prefix + resumed == full
 
 
+# ---------------------------------------------------------------------------
+# per-slot state in the cache's own layout, updated in place
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module", params=["mamba2-130m", "zamba2-2.7b"])
+def state_engine(request):
+    """A reduced engine of a family with recurrent state per slot."""
+    cfg = ARCHITECTURES[request.param].reduced()
+    api = build_model(cfg, attn_impl="xla")
+    params = api.init(jax.random.PRNGKey(0))
+    return ServeEngine(api, params, max_active=3, view_len=8,
+                       block_size=BLOCK), cfg
+
+
+def _step_program(engine):
+    tables = (engine.paged.tables if engine.paged is not None
+              else jnp.zeros((engine.max_active, 1), jnp.int32))
+    pools = engine.paged.pools if engine.paged is not None else {}
+    return engine._step.lower(
+        engine.params, pools, tables, engine.opaque, engine.lengths,
+        engine.cur_tokens, jnp.zeros((engine.max_active,), bool)), None
+
+
+def _write_program(engine):
+    cache = jax.eval_shape(
+        lambda: engine.api.init_cache(1, engine.view_len))
+    opq = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
+                       engine._opaque(cache))
+    vocab = engine.api.cfg.vocab_size
+    # the slot's length and first token are donated beside the state
+    return engine._write_slot.lower(
+        engine.opaque, engine.lengths, engine.cur_tokens, 1, opq, 5,
+        jnp.zeros((vocab,), jnp.float32)), 2 * 4 * engine.max_active
+
+
+@pytest.mark.parametrize("program", [_step_program, _write_program],
+                         ids=["decode_step", "slot_write"])
+def test_engine_state_stays_in_cache_layout_and_in_place(state_engine,
+                                                         program):
+    """The slots sit on the cache's batch axis (axis 1 of the SSM state),
+    so neither the decode step nor the admission's slot write transposes
+    a stored state leaf. The slot write aliases the stored state to its
+    output; the step writes a new state and aliases nothing (a donated
+    state costs a whole-state copy on the chip: tests/test_tpu_compile.py)."""
+    engine, cfg = state_engine
+    leaves = jax.tree.leaves(engine.opaque)
+    R = engine.max_active
+    assert leaves and all(a.shape[:3] == (cfg.num_layers, R, 1)
+                          for a in leaves)
+    mlir = {"float32": "f32", "bfloat16": "bf16"}
+    stored = {f"tensor<{'x'.join(map(str, a.shape))}x{mlir[str(a.dtype)]}>"
+              for a in leaves}
+    lowered, donated = program(engine)
+    text = lowered.as_text()
+    assert all(t in text for t in stored)
+    for line in text.splitlines():
+        if "stablehlo.transpose" in line:
+            types = line.rsplit(":", 1)[-1]
+            assert not any(t in types for t in stored), line
+    aliased = lowered.compile().memory_analysis().alias_size_in_bytes
+    if donated is None:
+        assert aliased == 0
+    else:
+        assert aliased == sum(a.nbytes for a in leaves) + donated
+
+
+def test_engine_slot_reuse_writes_only_that_slot():
+    """Admit A, step, release; then admit B into the same slot while C
+    runs in the other: B's tokens are B's alone (A's state does not leak
+    through the in-place write), and C's are untouched by either."""
+    cfg = ARCHITECTURES["mamba2-130m"].reduced()
+    api = build_model(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    view_len = 16
+    rng = np.random.default_rng(3)
+
+    def req(rid, n, new):
+        return Request(rid=rid, arrival_s=0.0, max_new=new, prompt=tuple(
+            int(x) for x in rng.integers(0, cfg.vocab_size, n)))
+
+    a, b, c = req(0, 7, 6), req(1, 5, 6), req(2, 6, 9)
+    engine = ServeEngine(api, params, max_active=2, view_len=view_len,
+                         block_size=BLOCK)
+    slot_c = engine.admit(c)
+    got_c = [int(np.asarray(engine.cur_tokens)[slot_c])]
+    slot_a = engine.admit(a)
+    for _ in range(3):
+        got_c.append(engine.step()[slot_c])
+    engine.release(slot_a)
+    slot_b = engine.admit(b)
+    assert slot_b == slot_a
+    got_b = [int(np.asarray(engine.cur_tokens)[slot_b])]
+    for _ in range(b.max_new - 1):
+        toks = engine.step()
+        got_b.append(toks[slot_b])
+        got_c.append(toks[slot_c])
+    assert got_b == _oracle_tokens(api, params, b, view_len, None)
+    assert got_c == _oracle_tokens(api, params, c, view_len, None)
+
+
 @pytest.mark.slow
 def test_engine_tp_tuned_bit_identical_2dev():
     """2-way TP through the committed artifact: engine tokens match the

@@ -124,6 +124,56 @@ def test_paged_attention_compiles(one_chip):
     assert KERNEL in txt
 
 
+def test_serving_state_is_updated_without_whole_state_copies(one_chip,
+                                                             monkeypatch):
+    """The serving engine's decode step and admission slot write at
+    mamba2-130m's widths (two layers, eight slots): the compiled programs
+    hold no copy or transpose whose result has a stored state leaf's
+    shape, and the slot write updates the stored state in place."""
+    import re
+    from repro.configs import ARCHITECTURES
+    from repro.models.registry import build_model
+    from repro.parallel import sharding
+    from repro.serve import ServeEngine
+
+    # one chip, whatever mesh an earlier test of this process set
+    monkeypatch.setattr(sharding, "_CURRENT_MESH", None)
+    cfg = ARCHITECTURES["mamba2-130m"].replace(num_layers=2)
+    api = build_model(cfg)
+    R, T = 8, 64
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(api.init,
+                                                  jax.random.PRNGKey(0)))
+    eng = ServeEngine(api, params, max_active=R, view_len=T)
+    opaque = jax.tree.map(on_chip, eng.opaque)
+    vec = jax.ShapeDtypeStruct((R,), jnp.int32, sharding=one_chip)
+    step = eng._step.lower(
+        params, {}, jax.ShapeDtypeStruct((R, 1), jnp.int32,
+                                         sharding=one_chip),
+        opaque, vec, vec,
+        jax.ShapeDtypeStruct((R,), jnp.bool_, sharding=one_chip)).compile()
+    one_req = jax.eval_shape(lambda: api.init_cache(1, T))
+    write = eng._write_slot.lower(
+        opaque, vec, vec, 3, jax.tree.map(on_chip, eng._opaque(one_req)),
+        5, jax.ShapeDtypeStruct((cfg.vocab_size,), jnp.float32,
+                                sharding=one_chip)).compile()
+
+    stored = {tuple(a.shape) for a in jax.tree.leaves(eng.opaque)}
+    assert stored == {(2, R, 1, 3, 1792), (2, R, 1, 24, 64, 128)}
+    moves = re.compile(r"= \w+\[([\d,]+)\]\{[^}]*\} (?:copy|transpose)\(")
+    for prog in (step, write):
+        for m in moves.finditer(prog.as_text()):
+            assert tuple(map(int, m.group(1).split(","))) not in stored, \
+                m.group(0)
+    nbytes = sum(a.nbytes for a in jax.tree.leaves(eng.opaque))
+    mem = write.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes    # (the chip pads vectors)
+    assert mem.temp_size_in_bytes < nbytes // 8
+
+
 @pytest.fixture(scope="module")
 def mesh4(topo):
     import numpy as np
@@ -158,3 +208,4 @@ def test_data_parallel_train_step_compiles_2x2(mesh4, monkeypatch, table):
                   donate_argnums=donate).lower(*args).compile().as_text()
     assert KERNEL in txt
     assert "all-reduce" in txt or "collective-permute" in txt
+
