@@ -19,6 +19,7 @@ from repro.configs import (  # noqa: E402
     smollm_135m,
     whisper_large_v3,
     zamba2_2p7b,
+    zamba2_7b,
 )
 
 ARCHITECTURES = {
@@ -34,8 +35,11 @@ ARCHITECTURES = {
         llava_next_mistral_7b,
         qwen2p5_3b,
         arctic_480b,
+        zamba2_7b,
     )
 }
+# the first pipeline stage of zamba2-7b, which one chip serves
+ARCHITECTURES[zamba2_7b.STAGE_18L.name] = zamba2_7b.STAGE_18L
 
 
 def get_config(name: str) -> ModelConfig:

@@ -44,8 +44,12 @@ class ModelConfig:
     d_conv: int = 4
     expand: int = 2
 
-    # --- hybrid (zamba2) ---
-    attn_every: int = 0  # apply the shared attention block every N ssm blocks
+    ssm_groups: int = 1  # B/C groups: heads g*H/G .. (g+1)*H/G - 1 read group g
+
+    # --- hybrid (zamba2); a hybrid config sets all three ---
+    hybrid_layer_ids: tuple = ()  # layers that also run a shared block
+    num_mem_blocks: int = 0       # shared blocks, used by turns
+    adapter_rank: int = 0         # per-use LoRA on the shared MLP's gate/up
 
     # --- position / attention flavour ---
     rope_theta: float = 10000.0
@@ -91,7 +95,8 @@ class ModelConfig:
         return dataclasses.replace(self, **kw)
 
     def reduced(self) -> "ModelConfig":
-        """Smoke-test variant: <=2 layers, d_model<=512, <=4 experts."""
+        """Smoke-test variant: <=2 layers (a hybrid keeps 6: three uses of
+        its shared blocks), d_model<=512, <=4 experts; SSM groups kept."""
         d_model = min(self.d_model, 256)
         num_heads = min(self.num_heads, 4)
         head_dim = min(self.resolved_head_dim, 64)
@@ -114,8 +119,11 @@ class ModelConfig:
             kw.update(dense_d_ff=min(self.dense_d_ff, 512))
         if self.ssm_state:
             kw.update(ssm_state=min(self.ssm_state, 16), ssm_chunk=32)
-        if self.attn_every:
-            kw.update(attn_every=1)
+        if self.hybrid_layer_ids:
+            # two blocks used by turns over three uses: block 0 twice
+            kw.update(num_layers=6, hybrid_layer_ids=(1, 3, 5),
+                      num_mem_blocks=min(self.num_mem_blocks, 2),
+                      adapter_rank=min(self.adapter_rank, 8))
         if self.encoder_layers:
             kw.update(encoder_layers=2, encoder_seq=min(self.encoder_seq, 64))
         if self.num_patches:

@@ -16,6 +16,7 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.attention import flash_attention
@@ -51,6 +52,15 @@ def attention(q, k, v, *, causal=True, window=0, q_offset=0, scale=None,
 
 
 def ssd(x, dt, A, B, C, D, *, chunk=128, impl="auto"):
+    """Chunked SSD. ``B``/``C`` are ``(batch, seq, state)``, shared by all
+    heads, or ``(batch, seq, groups, state)``: heads ``g*H/G ..
+    (g+1)*H/G - 1`` read group g, one call per group over its heads."""
+    if B.ndim == 4:
+        hg = x.shape[2] // B.shape[2]
+        return jnp.concatenate([
+            ssd(x[:, :, h:h + hg], dt[:, :, h:h + hg], A[h:h + hg],
+                B[:, :, g], C[:, :, g], D[h:h + hg], chunk=chunk, impl=impl)
+            for g, h in enumerate(range(0, x.shape[2], hg))], axis=2)
     if impl == "auto":
         impl = "pallas" if on_tpu() else "xla"
     if impl == "ref":

@@ -93,13 +93,14 @@ def ssd(
     x: jax.Array,        # (B, S, H, P)  head inputs
     dt: jax.Array,       # (B, S, H)     softplus'd step sizes (>0)
     A: jax.Array,        # (H,)          negative decay rates (A < 0)
-    Bm: jax.Array,       # (B, S, N)     input projection (shared across heads)
-    Cm: jax.Array,       # (B, S, N)     output projection
+    Bm: jax.Array,       # (B, S, N) shared across heads, or (B, S, G, N)
+    Cm: jax.Array,       # (B, S, N) output projection, or (B, S, G, N)
     D: jax.Array,        # (H,)          skip connection
 ) -> jax.Array:
     """y[t] = sum_{s<=t} C_t^T (prod_{r=s+1..t} e^{dt_r A}) dt_s B_s x_s + D x_t.
 
-    O(S^2) masked form — the oracle for the chunked kernel.
+    O(S^2) masked form — the oracle for the chunked kernel. With G
+    groups, head h reads group ``h // (H / G)``'s B and C.
     """
     xf = x.astype(jnp.float32)
     dtf = dt.astype(jnp.float32)
@@ -117,7 +118,13 @@ def ssd(
     # values whose exp overflows and poisons the backward of where()
     diff = jnp.where(tri[None, :, :, None], diff, -jnp.inf)
     decay = jnp.exp(diff)
-    scores = jnp.einsum("btn,bsn->bts", Cf, Bf)[..., None] * decay  # (B,S,S,H)
+    if Bf.ndim == 3:
+        scores = jnp.einsum("btn,bsn->bts", Cf, Bf)[..., None] * decay
+    else:                                            # per head's group
+        H = x.shape[2]
+        Bh = jnp.repeat(Bf, H // Bf.shape[2], axis=2)
+        Ch = jnp.repeat(Cf, H // Cf.shape[2], axis=2)
+        scores = jnp.einsum("bthn,bshn->btsh", Ch, Bh) * decay  # (B,S,S,H)
     scores = scores * dtf[:, None, :, :]             # weight by dt_s
     y = jnp.einsum("btsh,bshp->bthp", scores, xf)
     y = y + xf * D.astype(jnp.float32)[None, None, :, None]

@@ -42,11 +42,12 @@ def parallel_for(arch: str, shape_kind: str) -> ParallelConfig:
 
 
 def _acct_cfg(cfg, units: int):
-    """Config with ``units`` homogeneous layer-units (hybrid unit = one
-    mamba group + shared attention application; encdec unit = one encoder +
+    """Config with ``units`` layer-units (hybrid unit = the mamba layers
+    up to and including one hybrid layer; encdec unit = one encoder +
     one decoder layer)."""
     if cfg.family == "hybrid":
-        return cfg.replace(num_layers=units * cfg.attn_every)
+        ids = cfg.hybrid_layer_ids[:units]
+        return cfg.replace(num_layers=ids[-1] + 1, hybrid_layer_ids=ids)
     if cfg.family == "encdec":
         return cfg.replace(num_layers=units, encoder_layers=units)
     return cfg.replace(num_layers=units)
@@ -54,7 +55,8 @@ def _acct_cfg(cfg, units: int):
 
 def _units(cfg) -> int:
     if cfg.family == "hybrid":
-        return cfg.num_layers // cfg.attn_every
+        from repro.models.hybrid import n_uses
+        return n_uses(cfg)
     return cfg.num_layers
 
 

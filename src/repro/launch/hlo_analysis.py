@@ -136,10 +136,16 @@ def param_count(cfg, *, active_only: bool = False) -> float:
         per = d * (2 * di + 2 * N + Hs) + di * d + (cfg.d_conv) * (di + 2 * N)
         n += L * per
     elif cfg.family == "hybrid":
-        di, N, Hs = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
-        per = d * (2 * di + 2 * N + Hs) + di * d + (cfg.d_conv) * (di + 2 * N)
+        from repro.models.hybrid import n_uses
+        di, GN, Hs = cfg.d_inner, cfg.ssm_groups * cfg.ssm_state, \
+            cfg.ssm_heads
+        per = d * (2 * di + 2 * GN + Hs) + di * d \
+            + cfg.d_conv * (di + 2 * GN)
         n += L * per
-        n += attn + 3 * d * cfg.d_ff                   # ONE shared block
+        w = 2 * d                                      # reads [h ; e]
+        block = w * (H + 2 * KV) * Dh + H * Dh * d + 3 * d * cfg.d_ff
+        n += cfg.num_mem_blocks * block                # the shared blocks
+        n += n_uses(cfg) * (d * d + cfg.adapter_rank * (d + 2 * cfg.d_ff))
     elif cfg.family == "encdec":
         n += cfg.encoder_layers * (attn + 2 * d * cfg.d_ff)
         n += L * (2 * attn + 2 * d * cfg.d_ff)         # self + cross

@@ -95,6 +95,11 @@ def _serve_continuous(args, cfg, api, params, comm, mesh):
         print(f"SLO p99 <= {args.slo_ms:.0f} ms: "
               f"{'met' if ok else 'MISSED'}")
 
+    if args.trace_dir and engine.paged is not None:
+        print(f"paged KV: {res.counters.get('kv_blocks_peak'):.0f} blocks "
+              f"held at most of {engine.paged.pool_mgr.num_blocks - 1}, "
+              f"{res.counters.get('kv_view_bytes'):.0f} bytes of views "
+              f"gathered a step")
     if args.trace_dir:
         import os
         os.makedirs(args.trace_dir, exist_ok=True)
@@ -166,7 +171,9 @@ def main(argv=None):
                          "latency percentiles + throughput + config; "
                          "with --continuous also per-request records and "
                          "the engine run's counters: admissions, decode "
-                         "steps, garbage collections and their seconds)")
+                         "steps, garbage collections and their seconds, "
+                         "KV blocks held at most and KV view bytes a "
+                         "step, which it also prints)")
     args = ap.parse_args(argv)
     enable_compile_cache()
     # serving places its arrays itself; a training mesh left by an earlier

@@ -209,8 +209,14 @@ def attention_block(
     return_kv: bool = False,      # prefill: return this block's k/v for caching
     compute_dtype=jnp.bfloat16,
     attn_impl: str = "auto",
+    scale: Optional[float] = None,  # softmax scale; None: head_dim ** -0.5
 ):
-    """Returns (out, new_kv) — new_kv is None unless kv_cache/return_kv given."""
+    """Returns (out, new_kv) — new_kv is None unless kv_cache/return_kv given.
+
+    A cache of flattened heads ``(B, T, KV*Dh)`` (the hybrid's) is read as
+    it is, the new token's k/v attended to beside it, and not written:
+    ``new_kv`` holds only the token's k/v, ``(B, 1, KV*Dh)``, for the
+    caller to store at ring slot ``length % T``."""
     cd = compute_dtype
     q = jnp.einsum("bsd,dhk->bshk", x.astype(cd), p["wq"].astype(cd))
     k = jnp.einsum("bsd,dhk->bshk", x.astype(cd), p["wk"].astype(cd))
@@ -230,17 +236,32 @@ def attention_block(
         T = kv_cache["k"].shape[1]
         slot = kv_cache["length"] % T
         cache_dt = kv_cache["k"].dtype
-        ck = jax.lax.dynamic_update_slice_in_dim(
-            kv_cache["k"], k.astype(cache_dt), slot, 1)
-        cv = jax.lax.dynamic_update_slice_in_dim(
-            kv_cache["v"], v.astype(cache_dt), slot, 1)
         new_len = kv_cache["length"] + x.shape[1]
-        new_kv = {"k": ck, "v": cv, "length": new_len}
-        slot_pos = ring_slot_positions(new_len, T)
-        out = cache_attention(q, ck, cv, positions, slot_pos, window=window)
+        if kv_cache["k"].ndim == 3:     # (B, T, KV*Dh): heads flattened
+            # the cache as it was, its slot `slot` masked, and the new
+            # token beside it: the cache read is never copied
+            assert x.shape[1] == 1, "a flat cache decodes one token"
+            k = k.reshape(*k.shape[:2], -1).astype(cache_dt)
+            v = v.reshape(*v.shape[:2], -1).astype(cache_dt)
+            slot_pos = ring_slot_positions(kv_cache["length"], T)
+            slot_pos = jnp.where(jnp.arange(T) == slot, -1, slot_pos)
+            sc = q.shape[-1] ** -0.5 if scale is None else scale
+            out = _flat_cache_attention(q * sc, kv_cache["k"], kv_cache["v"],
+                                        positions, slot_pos, window=window,
+                                        new=(k, v))
+            new_kv = {"k": k, "v": v, "length": new_len}
+        else:
+            ck = jax.lax.dynamic_update_slice_in_dim(
+                kv_cache["k"], k.astype(cache_dt), slot, 1)
+            cv = jax.lax.dynamic_update_slice_in_dim(
+                kv_cache["v"], v.astype(cache_dt), slot, 1)
+            new_kv = {"k": ck, "v": cv, "length": new_len}
+            slot_pos = ring_slot_positions(new_len, T)
+            out = cache_attention(q, ck, cv, positions, slot_pos,
+                                  window=window, scale=scale)
     else:
         out = ops.attention(q, k, v, causal=causal, window=window,
-                            impl=attn_impl)
+                            scale=scale, impl=attn_impl)
         if return_kv:
             new_kv = {"k": k, "v": v}
     out = jnp.einsum("bshk,hkd->bsd", out.astype(cd), p["wo"].astype(cd))
@@ -258,7 +279,7 @@ def ring_slot_positions(length, T: int):
     return jnp.where(i < length, last, -1)
 
 
-def cache_attention(q, ck, cv, q_pos, slot_pos, *, window=0):
+def cache_attention(q, ck, cv, q_pos, slot_pos, *, window=0, scale=None):
     """Decode attention against a (possibly ring-buffered) KV cache.
 
     q: (B, 1, H, Dh); ck/cv: (B, T, KV, Dh); q_pos: (1,) absolute;
@@ -270,19 +291,56 @@ def cache_attention(q, ck, cv, q_pos, slot_pos, *, window=0):
     decode step), with fp32 accumulation via preferred_element_type.
     """
     B, S, H, Dh = q.shape
+    scale = Dh ** -0.5 if scale is None else scale
     KV = ck.shape[2]
     group = H // KV
-    qr = (q * (Dh ** -0.5)).reshape(B, S, KV, group, Dh).astype(ck.dtype)
+    qr = (q * scale).reshape(B, S, KV, group, Dh).astype(ck.dtype)
     logits = jnp.einsum("bskgd,btkd->bkgst", qr, ck,
                         preferred_element_type=jnp.float32)
-    valid = (slot_pos >= 0) & (slot_pos <= q_pos[0])
-    if window > 0:
-        valid &= slot_pos > q_pos[0] - window
+    valid = _cache_mask(q_pos, slot_pos, window)
     logits = jnp.where(valid[None, None, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgst,btkd->bskgd", probs.astype(cv.dtype), cv,
                      preferred_element_type=jnp.float32)
     return out.reshape(B, S, H, Dh).astype(q.dtype)
+
+
+def _cache_mask(q_pos, slot_pos, window):
+    valid = (slot_pos >= 0) & (slot_pos <= q_pos[0])
+    if window > 0:
+        valid &= slot_pos > q_pos[0] - window
+    return valid
+
+
+def _flat_cache_attention(q, ck, cv, q_pos, slot_pos, *, window=0,
+                          new=None):
+    """:func:`cache_attention` of one query over a ``(B, T, KV*Dh)`` cache
+    (heads flattened, for a head width that is no multiple of 128: a TPU
+    lays such an array out with another axis minor, and then copies every
+    view it gathers), read as it is stored: each query spread over its KV
+    head's channels, zero elsewhere, against all channels. ``q`` is
+    already scaled; ``new``, the decoded token's own ``(k, v)``, each
+    ``(B, 1, KV*Dh)``, is attended to beside the cache."""
+    B, _, H, Dh = q.shape
+    T, KV = ck.shape[1], ck.shape[-1] // Dh
+    mine = (jnp.arange(KV * Dh)[None] // Dh
+            == (jnp.arange(H) // (H // KV))[:, None])       # (H, KV*Dh)
+    qf = jnp.where(mine, jnp.tile(q[:, 0], (1, 1, KV)), 0).astype(ck.dtype)
+    logits = jnp.einsum("bhf,btf->bht", qf, ck,
+                        preferred_element_type=jnp.float32)
+    valid = _cache_mask(q_pos, slot_pos, window)
+    logits = jnp.where(valid[None, None, :], logits, -1e30)
+    if new is not None:
+        own = jnp.einsum("bhf,btf->bht", qf, new[0],
+                         preferred_element_type=jnp.float32)
+        logits = jnp.concatenate([logits, own], -1)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bht,btf->bhf", probs[..., :T].astype(cv.dtype), cv,
+                     preferred_element_type=jnp.float32)
+    if new is not None:
+        out = out + probs[..., T:] * new[1].astype(jnp.float32)
+    out = jnp.where(mine, out, 0).reshape(B, H, KV, Dh).sum(2)
+    return out[:, None].astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

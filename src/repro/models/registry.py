@@ -23,6 +23,9 @@ class ModelAPI:
     # (params, tokens, cache_len, **extra) -> (logits (B,S,V), primed cache);
     # extra carries per-family inputs (encdec: audio=...)
     prefill: Optional[Callable[..., tuple]] = None
+    # decode_step takes token_kv=True: its new cache's k/v are then only
+    # the token it wrote (hybrid), not whole new views
+    token_kv: bool = False
 
 
 _FAMILY = {
@@ -97,6 +100,7 @@ def build_model(
         init_cache=init_cache,
         decode_step=decode,
         prefill=prefill,
+        token_kv=cfg.family == "hybrid",
     )
 
 

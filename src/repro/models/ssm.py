@@ -14,12 +14,13 @@ from repro.models import layers as L
 
 
 def conv_dim(cfg: ModelConfig) -> int:
-    return cfg.d_inner + 2 * cfg.ssm_state
+    return cfg.d_inner + 2 * cfg.ssm_groups * cfg.ssm_state
 
 
 def proj_dim(cfg: ModelConfig) -> int:
-    # [z (d_inner) | xBC (d_inner + 2N) | dt (H)]
-    return 2 * cfg.d_inner + 2 * cfg.ssm_state + cfg.ssm_heads
+    # [z (d_inner) | xBC (d_inner + 2GN) | dt (H)]
+    return 2 * cfg.d_inner + 2 * cfg.ssm_groups * cfg.ssm_state \
+        + cfg.ssm_heads
 
 
 def ssm_params(key, cfg: ModelConfig, layers: Optional[int] = None,
@@ -69,11 +70,23 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
 
 
 def _split_proj(zxbcdt, cfg: ModelConfig):
-    di, N, H = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
+    di, GN = cfg.d_inner, cfg.ssm_groups * cfg.ssm_state
     z = zxbcdt[..., :di]
-    xBC = zxbcdt[..., di:2 * di + 2 * N]
-    dt = zxbcdt[..., 2 * di + 2 * N:]
+    xBC = zxbcdt[..., di:2 * di + 2 * GN]
+    dt = zxbcdt[..., 2 * di + 2 * GN:]
     return z, xBC, dt
+
+
+def _split_bc(xBC, cfg: ModelConfig):
+    """B and C out of the convolved xBC: ``(..., N)`` for one group, else
+    ``(..., G, N)``."""
+    di, G, N = cfg.d_inner, cfg.ssm_groups, cfg.ssm_state
+    Bm = xBC[..., di:di + G * N]
+    Cm = xBC[..., di + G * N:]
+    if G > 1:
+        Bm = Bm.reshape(*Bm.shape[:-1], G, N)
+        Cm = Cm.reshape(*Cm.shape[:-1], G, N)
+    return Bm, Cm
 
 
 def ssm_block(
@@ -88,10 +101,15 @@ def ssm_block(
 ):
     """Returns (out, new_state) — new_state None unless ``state`` given or
     ``return_state`` (prefill: sequence-mode outputs plus the conv/ssd state
-    a subsequent ``decode_step`` continues from)."""
+    a subsequent ``decode_step`` continues from).
+
+    With ``cfg.ssm_groups`` G > 1, heads ``g*H/G .. (g+1)*H/G - 1`` read
+    group g's B and C, and the gated norm normalises each group's
+    ``d_inner / G`` channels on their own (Mamba-2's ``ngroups``). One
+    group is the plain Mamba-2 mixer."""
     cd = compute_dtype
     B_, S, _ = x.shape
-    H, N, P = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim
+    H, N, P, G = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim, cfg.ssm_groups
     di = cfg.d_inner
 
     zxbcdt = jnp.einsum("bsd,dp->bsp", x.astype(cd), p["in_proj"].astype(cd))
@@ -112,8 +130,7 @@ def ssm_block(
             xBC, conv_state = _causal_conv(xBC, p["conv_w"].astype(cd),
                                            p["conv_b"].astype(cd))
         xs = xBC[..., :di].reshape(B_, S, H, P)
-        Bm = xBC[..., di:di + N]
-        Cm = xBC[..., di + N:]
+        Bm, Cm = _split_bc(xBC, cfg)
         y = ops.ssd(xs, dt, A, Bm, Cm, p["D"].astype(jnp.float32),
                     chunk=min(cfg.ssm_chunk, S), impl=ssd_impl)
         y = y.reshape(B_, S, di)
@@ -124,29 +141,53 @@ def ssm_block(
             # with D the inclusive cumsum of dt.
             cum = jnp.cumsum(dt, axis=1)                       # (B,S,H)
             decay = jnp.exp((cum[:, -1:] - cum) * A[None, None, :])
-            ssd_state = jnp.einsum(
-                "bsh,bsn,bshp->bhpn", dt * decay,
-                Bm.astype(jnp.float32), xs.astype(jnp.float32))
+            if G == 1:
+                ssd_state = jnp.einsum(
+                    "bsh,bsn,bshp->bhpn", dt * decay,
+                    Bm.astype(jnp.float32), xs.astype(jnp.float32))
+            else:
+                ssd_state = jnp.einsum(
+                    "bsgh,bsgn,bsghp->bghpn",
+                    (dt * decay).reshape(B_, S, G, H // G),
+                    Bm.astype(jnp.float32),
+                    xs.astype(jnp.float32).reshape(B_, S, G, H // G, P),
+                ).reshape(B_, H, P, N)
             new_state = {"conv": conv_state, "ssd": ssd_state}
     else:
         xBC, conv_state = _causal_conv(xBC, p["conv_w"].astype(cd),
                                        p["conv_b"].astype(cd), state["conv"])
         xs = xBC[..., :di].reshape(B_, S, H, P)[:, 0]        # (B,H,P)
-        Bm = xBC[:, 0, di:di + N]                            # (B,N)
-        Cm = xBC[:, 0, di + N:]
+        if G == 1:
+            Bm = xBC[:, 0, di:di + N]                        # (B,N)
+            Cm = xBC[:, 0, di + N:]
+        else:
+            Bm, Cm = _split_bc(xBC[:, 0], cfg)               # (B,G,N)
         dt0 = dt[:, 0]                                       # (B,H)
         a = jnp.exp(dt0 * A[None, :])                        # (B,H)
-        upd = jnp.einsum("bh,bn,bhp->bhpn", dt0, Bm.astype(jnp.float32),
-                         xs.astype(jnp.float32))
-        ssd_state = state["ssd"] * a[..., None, None] + upd
-        y = jnp.einsum("bn,bhpn->bhp", Cm.astype(jnp.float32), ssd_state)
+        if G == 1:
+            upd = jnp.einsum("bh,bn,bhp->bhpn", dt0, Bm.astype(jnp.float32),
+                             xs.astype(jnp.float32))
+            ssd_state = state["ssd"] * a[..., None, None] + upd
+            y = jnp.einsum("bn,bhpn->bhp", Cm.astype(jnp.float32), ssd_state)
+        else:   # each head reads its group's B and C
+            Bh = jnp.repeat(Bm.astype(jnp.float32), H // G, axis=1)
+            Ch = jnp.repeat(Cm.astype(jnp.float32), H // G, axis=1)
+            upd = jnp.einsum("bh,bhn,bhp->bhpn", dt0, Bh,
+                             xs.astype(jnp.float32))
+            ssd_state = state["ssd"] * a[..., None, None] + upd
+            y = jnp.einsum("bhn,bhpn->bhp", Ch, ssd_state)
         y = y + xs.astype(jnp.float32) * p["D"].astype(jnp.float32)[None, :, None]
         y = y.reshape(B_, 1, di).astype(cd)
         new_state = {"conv": conv_state, "ssd": ssd_state}
 
-    # gated RMSNorm then out-projection
+    # gated RMSNorm (per group) then out-projection
     y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    y = L.rms_norm(y.astype(cd), p["norm"], cfg.norm_eps)
+    if G == 1:
+        y = L.rms_norm(y.astype(cd), p["norm"], cfg.norm_eps)
+    else:
+        y = L.rms_norm(y.astype(cd).reshape(B_, S, G, di // G),
+                       p["norm"].reshape(G, di // G),
+                       cfg.norm_eps).reshape(B_, S, di)
     out = jnp.einsum("bsi,id->bsd", y.astype(cd), p["out_proj"].astype(cd))
     return out.astype(x.dtype), new_state
 

@@ -62,7 +62,8 @@ def param_specs(params_tree, cfg: ModelConfig, parallel: ParallelConfig,
         name = _path_str(path)
         shape = leaf.shape
         last = name.rsplit("/", 1)[-1]
-        stacked = name.startswith("layers") or "/encoder/" in name \
+        stacked = name.startswith(("layers", "shared", "uses")) \
+            or "/encoder/" in name \
             or "/decoder/" in name or name.startswith("encoder") \
             or name.startswith("decoder")
         off = 1 if (stacked and len(shape) >= 2) else 0

@@ -20,14 +20,19 @@ family cache from ``api.init_cache(batch=1, view_len)`` is split into:
     scalar under vmap), replacing the cache's scalar ``length``.
 
 One jitted step gathers the views, runs ``jax.vmap(api.decode_step)``
-with batch-1 per request, scatters each request's newly written KV slot
-back into its blocks, and argmaxes the next token. The scan over layers
-writes the new state layer by layer into its own output buffer, so the
-step does not donate the old one: on a TPU a donated input makes XLA
-copy the whole new state into the old buffer after the loop. Admission
+with batch-1 per request, writes the token's k/v each request produced
+into its block (a family whose ``api.token_kv`` is set returns only
+those; from another's whole new view they are read at the ring slot),
+and argmaxes the next token. The step donates the KV pools, which it
+updates in place. The scan over layers writes the new state layer by
+layer into its own output buffer, so the step does not donate the old
+state: on a TPU a donated input makes XLA copy the whole new state into
+the old buffer after the loop. Admission
 writes a request's prefilled state, length and first token into its
 slot with one more jitted program, which donates what it updates and so
-writes only that slot, in place. Neither copies the whole state.
+writes only that slot, in place, and its prefilled KV into its blocks
+the same way (`PagedKV.write_view`). None copies the whole state or a
+whole pool.
 
 Each vmap instance is exactly the dense single-request decode — paged
 serving is therefore bit-identical to the per-request dense oracle by
@@ -57,6 +62,7 @@ host was doing in each idle gap:
     serve.schedule          admission policy, retire and release
     serve.admit (rid, prompt_len, slot)
       serve.prefill         prompt upload and the jitted prefill
+      serve.admit.kv        the prefilled KV written into the slot's blocks
       serve.admit.state     the slot's state, length and first token
       serve.admit.first_token   reading the first token back
     serve.step (active)     one decode step, tokens handed to the scheduler
@@ -67,7 +73,10 @@ host was doing in each idle gap:
 The simulated clock records the same spans. Each run also returns its
 counters (`ServeResult.counters`): ``admissions``, ``decode_steps``,
 ``gc_collections`` by generation, ``gc_s`` and ``gc_max_s`` (the
-longest single collection), with or without a profiler.
+longest single collection), with or without a profiler; and with paged
+KV, ``kv_blocks_peak`` (the most pool blocks held at once) and
+``kv_view_bytes`` (the bytes of dense KV views one decode step
+gathers).
 """
 from __future__ import annotations
 
@@ -82,7 +91,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import MetricsRegistry, span
-from repro.serve.paged_kv import PagedKV, gather_views, scatter_tokens
+from repro.serve.paged_kv import PagedKV, gather_views, write_tokens
 
 PAGED_LEAVES = ("k", "v")
 
@@ -184,6 +193,16 @@ class ServeEngine:
         self._step = self._build_step()
         self._write_slot = self._build_write_slot()
 
+    @property
+    def kv_view_bytes(self) -> int:
+        """Bytes of the dense KV views one decode step gathers: every
+        slot's whole view of every paged leaf."""
+        if self.paged is None:
+            return 0
+        return self.max_active * sum(
+            pool.dtype.itemsize * pool.shape[0] * self.view_len
+            * int(np.prod(pool.shape[3:])) for pool in self.paged.pools.values())
+
     def _opaque(self, cache):
         """The cache's per-request state the engine stores per slot."""
         return {n: v for n, v in cache.items()
@@ -208,19 +227,26 @@ class ServeEngine:
         tp, ax, collective = self._tp, self._axis, self._collective
         comm, axes = self._comm, self._slot_axes
 
+        kw = {"token_kv": True} if api.token_kv else {}
+
         def one(params, view, opq, ln, tok):
             cache = {**opq, **view}
             if has_length:
                 cache["length"] = ln
-            logits, nc = api.decode_step(params, cache, tok[None, None])
+            logits, nc = api.decode_step(params, cache, tok[None, None], **kw)
             new_len = nc.pop("length", ln + 1)
-            paged_out = {n: nc.pop(n) for n in paged_names}
-            return logits[0], paged_out, nc, new_len
+            # the token this step wrote: all a token_kv step returns, else
+            # ring slot ln % T of the whole new view
+            at = 0 if api.token_kv else ln % T
+            written = {n: jax.lax.dynamic_index_in_dim(
+                nc.pop(n)[:, 0], at, axis=1, keepdims=False)
+                for n in paged_names}
+            return logits[0], written, nc, new_len
 
         def step(params, pools, tables, opaque, lengths, tokens, active):
             views = (gather_views(pools, tables, bs) if paged_names else {})
-            logits, new_views, new_opq, new_lens = jax.vmap(
-                one, in_axes=(None, 0, axes, 0, 0),
+            logits, written, new_opq, new_lens = jax.vmap(
+                one, in_axes=(None, 1, axes, 0, 0),
                 out_axes=(0, 0, axes, 0))(
                 params, views, opaque, lengths, tokens)
             if tp:
@@ -247,7 +273,7 @@ class ServeEngine:
                     logits = apply_collective("all_reduce", masked, ax, tp,
                                               spec)
             pos = lengths % T
-            new_pools = (scatter_tokens(pools, tables, new_views, pos, bs)
+            new_pools = (write_tokens(pools, tables, written, pos, bs)
                          if paged_names else pools)
             new_lengths = jnp.where(active, new_lens, lengths)
             next_tok = jnp.argmax(logits, -1).astype(jnp.int32)
@@ -260,7 +286,7 @@ class ServeEngine:
                 step, mesh=self._mesh,
                 in_specs=(P(),) * 7, out_specs=(P(),) * 5,
                 check_vma=False)
-        return jax.jit(step)
+        return jax.jit(step, donate_argnums=(1,))
 
     def _build_write_slot(self):
         """One program that puts an admitted request's prefilled state,
@@ -296,10 +322,11 @@ class ServeEngine:
             tokens = jnp.asarray(np.asarray(req.prompt, np.int32))[None]
             logits, cache = self._prefill(self.params, tokens,
                                           **self.prefill_extra(req))
-        with span("serve.admit.state"):
-            if self.paged is not None:
+        if self.paged is not None:
+            with span("serve.admit.kv"):
                 self.paged.write_view(slot, {n: cache[n]
                                              for n in self.paged_names})
+        with span("serve.admit.state"):
             self.opaque, self.lengths, self.cur_tokens = self._write_slot(
                 self.opaque, self.lengths, self.cur_tokens, slot,
                 self._opaque(cache), req.prompt_len, logits[0, -1])
@@ -370,6 +397,8 @@ class ServeEngine:
                 for r in list(sched.pending):
                     r.arrival_s += base
 
+            if self.paged is not None:
+                counters.observe_max("kv_view_bytes", self.kv_view_bytes)
             steps = 0
             while not sched.done and steps < max_steps:
                 steps += 1
@@ -399,6 +428,9 @@ class ServeEngine:
                             tok0 = int(np.asarray(self.cur_tokens)[slot])
                         sched.record_token(req, tok0, now)
                         counters.inc("admissions")
+                        if self.paged is not None:
+                            counters.observe_max("kv_blocks_peak",
+                                                 self.paged.blocks_held)
                 stepped = bool(sched.active)
                 if stepped:
                     with span("serve.step", active=len(sched.active)):

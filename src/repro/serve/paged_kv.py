@@ -21,48 +21,75 @@ eviction/refill case the tests exercise.
 """
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 
 def gather_views(pools, tables, block_size: int):
-    """Pure form of :meth:`PagedKV.gather` (jit-friendly).
+    """The dense per-request views, jit-friendly.
 
     pools: leaf -> (lead, NB, bs, ...); tables: (R, nb) int32.
-    Returns leaf -> (R, lead, 1, nb*bs, ...): the dense per-request views,
-    request axis leading so the result vmaps directly over slots.
+    Returns leaf -> (lead, R, 1, nb*bs, ...): the requests on the batch
+    axis of a batch-1 cache leaf ``(lead, 1, T, ...)``, where the gather
+    puts them, so the result vmaps over slots (``in_axes=1``) with no
+    transpose of the views.
     """
     out = {}
     R, nb = tables.shape
     for name, pool in pools.items():
-        v = pool[:, tables]                        # (lead, R, nb, bs, ...)
-        v = jnp.moveaxis(v, 1, 0)                  # (R, lead, nb, bs, ...)
-        lead = v.shape[1]
-        v = v.reshape(R, lead, nb * block_size, *v.shape[4:])
-        out[name] = v[:, :, None]                  # (R, lead, 1, T, ...)
+        # one gather of whole blocks per lead index: on a TPU a gather
+        # under the lead axis puts the requests first and transposes
+        v = jnp.stack([pool[i][tables] for i in range(pool.shape[0])])
+        v = v.reshape(v.shape[0], R, nb * block_size, *v.shape[4:])
+        out[name] = v[:, :, None]                  # (lead, R, 1, T, ...)
     return out
 
 
-def scatter_tokens(pools, tables, views, positions, block_size: int):
-    """Pure form of :meth:`PagedKV.scatter_token` (jit-friendly).
-
-    Writes back the single view slot each request just filled and returns
-    the new pools. ``positions`` is the ``(R,)`` ring slot written
-    (``old_length % view_len``). Live block tables are disjoint, so the
-    scatter has no collisions; inactive slots target the null block,
-    whose contents are never read as valid.
-    """
+def write_tokens(pools, tables, tokens, positions, block_size: int):
+    """Write each request's one new token ``tokens[leaf]`` ``(R, lead,
+    ...)`` at its ring slot ``positions`` ``(R,)`` and return the new
+    pools. Live block tables are disjoint, so no two writes collide;
+    inactive slots target the null block, whose contents are
+    never read as valid."""
     R = tables.shape[0]
     pos = jnp.asarray(positions, jnp.int32)
     blk = tables[jnp.arange(R), pos // block_size]      # (R,)
     off = pos % block_size                              # (R,)
-    new_pools = {}
+    out = {}
+    for name, vals in tokens.items():
+        # one in-place slice update per slot: a scatter would relay the
+        # whole pool out on a TPU
+        pool = pools[name]
+        for r in range(R):
+            pool = jax.lax.dynamic_update_slice(
+                pool, vals[r][:, None, None].astype(pool.dtype),
+                (0, blk[r], off[r]) + (0,) * (pool.ndim - 3))
+        out[name] = pool
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("block_size",),
+                   donate_argnums=(0,))
+def _write_blocks(pools, blocks, views, *, block_size: int):
+    """Each view ``(lead, 1, T, ...)`` into its pool's ``blocks`` in
+    place: one slice update per block (the pools are donated; a scatter,
+    or an eager update, would write a whole new pool)."""
+    out = {}
     for name, view in views.items():
-        # written token per request: (R, lead, ...)
-        vals = view[jnp.arange(R), :, 0, pos]
-        vals = jnp.moveaxis(vals, 0, 1)                 # (lead, R, ...)
-        new_pools[name] = pools[name].at[:, blk, off].set(vals)
-    return new_pools
+        v = view[:, 0]
+        v = v.reshape(v.shape[0], -1, block_size, *v.shape[2:])
+
+        def body(i, pool, v=v):
+            blk = jax.lax.dynamic_index_in_dim(v, i, axis=1).astype(
+                pool.dtype)
+            return jax.lax.dynamic_update_slice(
+                pool, blk, (0, blocks[i]) + (0,) * (pool.ndim - 2))
+
+        out[name] = jax.lax.fori_loop(0, v.shape[1], body, pools[name])
+    return out
 
 
 class BlockPool:
@@ -140,6 +167,11 @@ class PagedKV:
     def available_blocks(self) -> int:
         return self.pool_mgr.available
 
+    @property
+    def blocks_held(self) -> int:
+        """Pool blocks the admitted slots hold now."""
+        return sum(len(b) for b in self._owned.values())
+
     def admit(self, slot: int) -> bool:
         """Allocate a full block table for request slot ``slot``."""
         if slot in self._owned:
@@ -175,32 +207,12 @@ class PagedKV:
 
         ``views`` maps leaf name -> ``(lead, 1, view_len, KV, Dh)`` (the
         batch-1 cache leaf). Used after prefill: the prefilled dense cache
-        leaf lands in the freshly allocated blocks.
+        leaf lands in the freshly allocated blocks, in place (the pools
+        are donated).
         """
-        blocks = tuple(self._owned[slot])
-        nb, bs = self.blocks_per_request, self.block_size
-        for name, view in views.items():
-            pool = self.pools[name]
-            v = jnp.asarray(view)
+        for v in views.values():
             assert v.shape[1] == 1 and v.shape[2] == self.view_len, v.shape
-            v = v[:, 0]                      # (lead, view_len, ...)
-            lead = v.shape[0]
-            v = v.reshape(lead, nb, bs, *v.shape[2:])
-            self.pools[name] = pool.at[:, blocks].set(v)
-
-    def gather(self):
-        """Dense views for every slot: leaf -> (R, lead, 1, view_len, ...).
-
-        Inactive slots read the null block (garbage, discarded).
-        """
-        return gather_views(self.pools, self.tables, self.block_size)
-
-    def scatter_token(self, views, positions) -> None:
-        """Write back the one view slot each request just filled.
-
-        ``views`` maps leaf name -> ``(R, lead, 1, view_len, ...)`` (the
-        post-decode dense views); ``positions`` is the ``(R,)`` int32 ring
-        slot each request wrote (``old_length % view_len``).
-        """
-        self.pools = scatter_tokens(self.pools, self.tables, views,
-                                    positions, self.block_size)
+        blocks = jnp.asarray(self._owned[slot], jnp.int32)
+        self.pools = {**self.pools, **_write_blocks(
+            {n: self.pools[n] for n in views}, blocks, views,
+            block_size=self.block_size)}

@@ -20,9 +20,17 @@ def key():
     return jax.random.PRNGKey(0)
 
 
+ASSIGNED = ("glm4-9b", "smollm-135m", "zamba2-2.7b", "whisper-large-v3",
+            "olmoe-1b-7b", "chatglm3-6b", "mamba2-130m",
+            "llava-next-mistral-7b", "qwen2.5-3b", "arctic-480b")
+
+
 def test_all_ten_architectures_registered():
-    assert len(ARCHITECTURES) == 10
-    fams = {c.family for c in ARCHITECTURES.values()}
+    """The ten assigned architectures, beside zamba2-7b and its first
+    pipeline stage, which the benchmark serves."""
+    assert set(ARCHITECTURES) == set(ASSIGNED) | {"zamba2-7b",
+                                                   "zamba2-7b-18l"}
+    fams = {ARCHITECTURES[a].family for a in ASSIGNED}
     assert fams == {"dense", "moe", "ssm", "hybrid", "encdec", "vlm"}
 
 
@@ -33,6 +41,8 @@ def test_full_config_matches_assignment(arch):
         "glm4-9b": (40, 4096, 32, 2, 13696, 151552),
         "smollm-135m": (30, 576, 9, 3, 1536, 49152),
         "zamba2-2.7b": (54, 2560, 32, 32, 10240, 32000),
+        "zamba2-7b": (81, 3584, 32, 32, 14336, 32000),
+        "zamba2-7b-18l": (18, 3584, 32, 32, 14336, 32000),
         "whisper-large-v3": (32, 1280, 20, 20, 5120, 51866),
         "olmoe-1b-7b": (16, 2048, 16, 16, 1024, 50304),
         "chatglm3-6b": (28, 4096, 32, 2, 13696, 65024),
@@ -51,6 +61,10 @@ def test_full_config_matches_assignment(arch):
         assert cfg.dense_residual
     if arch == "zamba2-2.7b":
         assert cfg.ssm_state == 64
+    if arch.startswith("zamba2-7b"):
+        assert (cfg.ssm_state, cfg.ssm_groups, cfg.head_dim,
+                cfg.num_mem_blocks, cfg.adapter_rank) == (64, 2, 224, 2, 128)
+        assert cfg.hybrid_layer_ids[:3] == (6, 11, 17)
     if arch == "mamba2-130m":
         assert cfg.ssm_state == 128
 
@@ -59,7 +73,9 @@ def test_full_config_matches_assignment(arch):
 def test_reduced_smoke_train_step(arch, key):
     """One forward+backward+update step, loss finite, grads finite."""
     cfg = get_config(arch).reduced()
-    assert cfg.num_layers == 2 and cfg.d_model <= 512
+    # a hybrid keeps three uses of its shared blocks
+    assert cfg.num_layers == (6 if cfg.hybrid_layer_ids else 2)
+    assert cfg.d_model <= 512
     if cfg.num_experts:
         assert cfg.num_experts <= 4
     api = build_model(cfg, compute_dtype=jnp.float32, attn_impl="ref",
@@ -119,8 +135,11 @@ def test_hybrid_shared_attention_is_shared(key):
     cfg = get_config("zamba2-2.7b").reduced()
     from repro.models import hybrid
     params = hybrid.init_params(key, cfg)
-    # exactly ONE attention block's worth of parameters, unstacked
-    assert params["shared"]["attn"]["wq"].ndim == 3
+    # num_mem_blocks attention blocks, shared by every hybrid layer; each
+    # use owns only its adapter and projection
+    assert params["shared"]["attn"]["wq"].shape[0] == cfg.num_mem_blocks == 2
+    assert params["shared"]["attn"]["wq"].ndim == 4
+    assert params["uses"]["linear"].shape[0] == hybrid.n_uses(cfg) == 3
 
 
 def test_sliding_window_changes_output(key):

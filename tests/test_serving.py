@@ -32,6 +32,7 @@ from repro.serve import (
     ServeEngine,
     synthetic_trace,
 )
+from repro.serve.paged_kv import gather_views, write_tokens
 
 HERE = os.path.dirname(__file__)
 
@@ -78,21 +79,25 @@ def test_paged_kv_write_gather_roundtrip():
     views = {n: jnp.asarray(rng.normal(size=(lead, 1, T, KV, Dh)),
                             jnp.float32) for n in ("k", "v")}
     kv.write_view(0, views)
-    got = kv.gather()
+    # the views come back with the slots on the batch axis: (lead, R, 1, T, ..)
+    got = gather_views(kv.pools, kv.tables, bs)
     for n in ("k", "v"):
-        np.testing.assert_array_equal(np.asarray(got[n][0]),
+        np.testing.assert_array_equal(np.asarray(got[n][:, 0]),
                                       np.asarray(views[n]))
-    # single-token scatter into slot 0's ring position 5 (block 1, off 1)
-    tok = {n: jnp.asarray(rng.normal(size=(2, lead, 1, T, KV, Dh)),
-                          jnp.float32) for n in ("k", "v")}
-    kv.scatter_token(tok, jnp.asarray([5, 0], jnp.int32))
-    got = kv.gather()
+    # one token per slot: slot 0's at ring position 5 (block 1, off 1)
+    tok = {n: jnp.asarray(rng.normal(size=(2, lead, KV, Dh)), jnp.float32)
+           for n in ("k", "v")}
+    kv.pools = write_tokens(kv.pools, kv.tables, tok,
+                            jnp.asarray([5, 0], jnp.int32), bs)
+    got = gather_views(kv.pools, kv.tables, bs)
     for n in ("k", "v"):
-        np.testing.assert_array_equal(np.asarray(got[n][0, :, 0, 5]),
-                                      np.asarray(tok[n][0, :, 0, 5]))
+        np.testing.assert_array_equal(np.asarray(got[n][:, 0, 0, 5]),
+                                      np.asarray(tok[n][0]))
         # the other slots of request 0 are untouched
-        np.testing.assert_array_equal(np.asarray(got[n][0, :, 0, :5]),
+        np.testing.assert_array_equal(np.asarray(got[n][:, 0, 0, :5]),
                                       np.asarray(views[n][:, 0, :5]))
+        np.testing.assert_array_equal(np.asarray(got[n][:, 0, 0, 6:]),
+                                      np.asarray(views[n][:, 0, 6:]))
 
     kv.release(0)
     assert kv.available_blocks == 2
@@ -265,21 +270,30 @@ def _dense_vmap_tokens(api, params, reqs, view_len, extra_fn):
     tables must not perturb a single bit vs contiguous dense storage.
     (The plain unbatched loop is NOT a bitwise oracle for every family:
     vmapping bf16 einsums can move last-bit rounding, which flips argmax
-    on exact logit ties.)"""
+    on exact logit ties.) The requests sit on each cache leaf's batch
+    axis, as the engine stores its slots, and are prefilled by the same
+    jitted program (an eager bf16 prefill rounds differently)."""
+    from repro.serve.engine import _batch_axis
+    axes = jax.tree.map(_batch_axis, *(
+        jax.eval_shape(lambda b=b: api.init_cache(b, view_len))
+        for b in (1, 2)))
+    prefill = jax.jit(lambda params, tokens, **extra:
+                      api.prefill(params, tokens, view_len, **extra))
     caches, toks = [], []
     for req in reqs:
         extra = extra_fn(req) if extra_fn else {}
         tokens = jnp.asarray(np.asarray(req.prompt, np.int32))[None]
-        logits, cache = api.prefill(params, tokens, view_len, **extra)
+        logits, cache = prefill(params, tokens, **extra)
         caches.append(cache)
         toks.append(int(jnp.argmax(logits[0, -1])))
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
+    stacked = jax.tree.map(lambda ax, *xs: jnp.stack(xs, ax), axes, *caches)
 
     def one(params, cache, tok):
         logits, nc = api.decode_step(params, cache, tok[None, None])
         return logits[0], nc
 
-    step = jax.jit(jax.vmap(one, in_axes=(None, 0, 0)))
+    step = jax.jit(jax.vmap(one, in_axes=(None, axes, 0),
+                            out_axes=(0, axes)))
     outs = [[t] for t in toks]
     tok = jnp.asarray(toks, jnp.int32)
     for _ in range(max(r.max_new for r in reqs) - 1):
@@ -441,8 +455,9 @@ def test_engine_state_stays_in_cache_layout_and_in_place(state_engine,
     """The slots sit on the cache's batch axis (axis 1 of the SSM state),
     so neither the decode step nor the admission's slot write transposes
     a stored state leaf. The slot write aliases the stored state to its
-    output; the step writes a new state and aliases nothing (a donated
-    state costs a whole-state copy on the chip: tests/test_tpu_compile.py)."""
+    output; the step writes a new state and aliases only the KV pools,
+    which it updates in place (a donated state costs a whole-state copy
+    on the chip: tests/test_tpu_compile.py)."""
     engine, cfg = state_engine
     leaves = jax.tree.leaves(engine.opaque)
     R = engine.max_active
@@ -460,7 +475,8 @@ def test_engine_state_stays_in_cache_layout_and_in_place(state_engine,
             assert not any(t in types for t in stored), line
     aliased = lowered.compile().memory_analysis().alias_size_in_bytes
     if donated is None:
-        assert aliased == 0
+        pools = engine.paged.pools if engine.paged is not None else {}
+        assert aliased == sum(a.nbytes for a in jax.tree.leaves(pools))
     else:
         assert aliased == sum(a.nbytes for a in leaves) + donated
 
@@ -590,9 +606,12 @@ def test_engine_spans_nest_on_profiler_clock(tiny_engine, tmp_path):
     assert sorted(s[4]["rid"] for s in admits) == [0, 1, 2]
     for a in admits:
         assert a[4]["prompt_len"] == 4 and a[4]["slot"] in (0, 1)
-        for child in ("serve.prefill", "serve.admit.state",
-                      "serve.admit.first_token"):
+        for child in ("serve.prefill", "serve.admit.kv",
+                      "serve.admit.state", "serve.admit.first_token"):
             assert len(inside(a, child)) == 1, (a, child)
+        # the prefilled KV is written into its blocks before the state
+        assert inside(a, "serve.admit.kv")[0][3] \
+            <= inside(a, "serve.admit.state")[0][2]
     steps = [s for s in spans if s[1] == "serve.step"]
     assert steps and all(1 <= s[4]["active"] <= 2 for s in steps)
     for st in steps:
@@ -617,6 +636,22 @@ def test_engine_run_counters_and_queue_wait(tiny_engine):
     assert first.first_token_s == pytest.approx(first.arrival_s + 1e-3)
     rec = {r["rid"]: r for r in res.records}[first.rid]
     assert rec["queue_ms"] == 0.0
+
+
+def test_engine_kv_counters_match_pool_and_views(tiny_engine):
+    """``kv_blocks_peak``: the blocks two requests held at once (three
+    requests over two slots); ``kv_view_bytes``: the dense views one
+    decode step gathers."""
+    engine, cfg = tiny_engine
+    res = engine.run(Scheduler(_tiny_trace(cfg.vocab_size), max_active=2,
+                               token_budget=24), cost_model=_Costs())
+    kv = engine.paged
+    assert res.counters.get("kv_blocks_peak") == 2 * kv.blocks_per_request
+    assert kv.blocks_held == 0                 # every request retired
+    views = jax.eval_shape(lambda: gather_views(kv.pools, kv.tables,
+                                                kv.block_size))
+    assert res.counters.get("kv_view_bytes") == engine.kv_view_bytes == sum(
+        v.size * v.dtype.itemsize for v in views.values())
 
 
 def test_engine_run_counts_gc_and_unhooks(tiny_engine):

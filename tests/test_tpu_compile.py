@@ -48,6 +48,15 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(autouse=True)
+def _no_mesh_of_an_earlier_test(monkeypatch):
+    """Kernel calls take the current sharding mesh: compile for the
+    described chip whatever mesh an earlier test of this worker left set
+    (a train step sets one)."""
+    from repro.parallel import sharding
+    monkeypatch.setattr(sharding, "_CURRENT_MESH", None)
+
+
 def _compile_text(fn, one_chip, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     return jax.jit(fn).lower(*args).compile().as_text()
@@ -172,6 +181,118 @@ def test_serving_state_is_updated_without_whole_state_copies(one_chip,
     mem = write.memory_analysis()
     assert mem.alias_size_in_bytes >= nbytes    # (the chip pads vectors)
     assert mem.temp_size_in_bytes < nbytes // 8
+
+
+# zamba2-7b: 32 attention heads of 224 (no multiple of 128), prefill of
+# the shared blocks' attention at a 2048-token prompt
+ZAMBA2 = dict(H=32, KV=32, D=224)
+
+
+@pytest.mark.parametrize("S", [2048, 64])
+def test_flash_attention_at_head_dim_224_compiles(one_chip, S):
+    H, KV, D = ZAMBA2["H"], ZAMBA2["KV"], ZAMBA2["D"]
+    fn = functools.partial(ops.attention, causal=True, scale=(D / 2) ** -0.5,
+                           impl="pallas")
+    txt = _compile_text(fn, one_chip, ((1, S, H, D), jnp.bfloat16),
+                        ((1, S, KV, D), jnp.bfloat16),
+                        ((1, S, KV, D), jnp.bfloat16))
+    assert KERNEL in txt
+
+
+@pytest.fixture(scope="module")
+def zamba2_engine(one_chip):
+    """The serving cell's engine: zamba2-7b's first 18 layers, bf16
+    weights, 8 slots, a 4096-token KV view in blocks of 16, as shapes on
+    a described chip (its pools are built at two blocks here: the step
+    takes the pools as arguments)."""
+    from repro.configs import ARCHITECTURES
+    from repro.models.registry import build_model
+    from repro.serve import ServeEngine
+    api = build_model(ARCHITECTURES["zamba2-7b-18l"])
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(api.init, jax.random.PRNGKey(0)))
+    R, T, bs = 8, 4096, 16
+    eng = ServeEngine(api, params, max_active=R, view_len=T, block_size=bs,
+                      num_blocks=2)
+    pools = {n: jax.ShapeDtypeStruct((p.shape[0], 1 + R * T // bs)
+                                     + p.shape[2:], p.dtype,
+                                     sharding=one_chip)
+             for n, p in eng.paged.pools.items()}
+    return api, params, eng, pools
+
+
+def test_zamba2_serving_step_fits_one_chip(zamba2_engine, one_chip,
+                                           monkeypatch):
+    """The decode step at the cell's sizes: weights, pools, state and the
+    step's own buffers fit in 16 GB; the pools are updated in place; no
+    whole stored state leaf is copied or transposed."""
+    import re
+    api, params, eng, pools = zamba2_engine
+    R = eng.max_active
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    vec = jax.ShapeDtypeStruct((R,), jnp.int32, sharding=one_chip)
+    tables = jax.ShapeDtypeStruct((R, eng.view_len // eng.block_size),
+                                  jnp.int32, sharding=one_chip)
+    step = eng._step.lower(
+        params, pools, tables, jax.tree.map(on_chip, eng.opaque), vec, vec,
+        jax.ShapeDtypeStruct((R,), jnp.bool_, sharding=one_chip)).compile()
+    mem = step.memory_analysis()
+    pool_bytes = sum(p.size * p.dtype.itemsize for p in pools.values())
+    assert mem.alias_size_in_bytes >= pool_bytes
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < 16e9, mem
+    stored = {tuple(a.shape) for a in jax.tree.leaves(eng.opaque)}
+    assert stored == {(18, R, 1, 3, 7424), (18, R, 1, 112, 64, 64)}
+    moves = re.compile(r"= \w+\[([\d,]+)\]\{[^}]*\} (?:copy|transpose)\(")
+    for m in moves.finditer(step.as_text()):
+        assert tuple(map(int, m.group(1).split(","))) not in stored, \
+            m.group(0)
+
+
+def test_zamba2_admission_writes_kv_blocks_in_place(zamba2_engine,
+                                                    one_chip):
+    """An admitted request's 4096-token KV view goes into its 256 blocks
+    of each pool in place: the pools are aliased, nothing pool-sized is
+    allocated beside them (at 80% of the chip's memory)."""
+    from repro.serve.paged_kv import _write_blocks
+    _, _, eng, pools = zamba2_engine
+    views = {n: jax.ShapeDtypeStruct(
+        (p.shape[0], 1, eng.view_len) + p.shape[3:], p.dtype,
+        sharding=one_chip) for n, p in pools.items()}
+    nb = eng.view_len // eng.block_size
+    mem = _write_blocks.lower(
+        pools, jax.ShapeDtypeStruct((nb,), jnp.int32, sharding=one_chip),
+        views, block_size=eng.block_size).compile().memory_analysis()
+    pool_bytes = sum(p.size * p.dtype.itemsize for p in pools.values())
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 1e6, mem
+
+
+def test_zamba2_prefill_compiles_with_both_kernels(zamba2_engine, one_chip,
+                                                   monkeypatch):
+    """A 2048-token prompt through the first 18 layers: the grouped SSD
+    kernel and the flash kernel at head width 224 are both in it, and it
+    fits beside the weights."""
+    from repro.kernels import ops as kops
+    monkeypatch.setattr(kops, "on_tpu", lambda: True)
+    api, params, eng, _ = zamba2_engine
+    toks = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one_chip)
+    prog = jax.jit(lambda p, t: api.prefill(p, t, eng.view_len)).lower(
+        params, toks).compile()
+    txt = prog.as_text()
+    calls = [line for line in txt.splitlines() if KERNEL in line
+             and "custom-call(" in line]
+    # flash: (heads, positions, 224); SSD: one call per group's 56 heads
+    assert any("= bf16[32,2048,224]" in c for c in calls)
+    assert any("= (f32[1,56,16,128,64]" in c for c in calls)
+    mem = prog.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8e9, mem
 
 
 @pytest.fixture(scope="module")

@@ -36,6 +36,15 @@ MS = tuple(1024 * 4 ** i for i in range(6))
 POINTS = [Point(o, p, m) for o in OPS for p in PS for m in MS]
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _hand_written_menu_only():
+    """These tuners rank the hand-written menu: forget the synthesized
+    fronts an earlier test of this worker adopted (a Communicator built
+    from a tuned artifact adopts the programs it carries)."""
+    from repro.core.collectives import synth
+    synth.clear_registry()
+
+
 @pytest.fixture(scope="module")
 def sim():
     return NetworkSimulator(NetworkProfile(seed=3))
