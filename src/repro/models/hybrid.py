@@ -229,17 +229,26 @@ def decode_step(params, cache, tokens, cfg: ModelConfig, *, window=0,
     ``k``/``v`` hold only this token's, ``(uses, batch, 1, KV*Dh)``, to be
     stored at ring slot ``length % view`` (the serving engine's path: the
     cache read is not copied); without it they are the caches with the
-    token written there."""
+    token written there. A cache with a ``block_table`` (one request's,
+    ``(nb,)``) holds the serving engine's whole block pools ``(uses, NB,
+    bs, KV*Dh)`` as ``k``/``v``, read through that table (token_kv only)."""
     e = T.embed_tokens(params, tokens, cfg, compute_dtype)
     length = cache["length"]
     positions = length[None]
+    paged = "block_table" in cache
+    assert token_kv or not paged, "block pools are read, not written here"
 
     new_k, new_v, runs = [], [], []
     x = e
     for lo, hi, k in _pieces(cfg):
         t = None
         if k is not None:
-            kv = {"k": cache["k"][k], "v": cache["v"][k], "length": length}
+            if paged:
+                kv = {"k": cache["k"], "v": cache["v"], "use": k,
+                      "block_table": cache["block_table"], "length": length}
+            else:
+                kv = {"k": cache["k"][k], "v": cache["v"][k],
+                      "length": length}
             t, nkv = _shared_out(x, e, params, k, cfg, positions, kv=kv,
                                  window=window, compute_dtype=compute_dtype,
                                  attn_impl="ref")

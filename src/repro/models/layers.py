@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
+from repro.kernels.paged_attention import gather_kv_view, paged_flat_stats
 
 Params = dict
 
@@ -216,7 +217,10 @@ def attention_block(
     A cache of flattened heads ``(B, T, KV*Dh)`` (the hybrid's) is read as
     it is, the new token's k/v attended to beside it, and not written:
     ``new_kv`` holds only the token's k/v, ``(B, 1, KV*Dh)``, for the
-    caller to store at ring slot ``length % T``."""
+    caller to store at ring slot ``length % T``. So is one request's
+    use ``use`` of the serving engine's flat block pools: ``kv_cache``
+    ``dict(k=(uses, NB, bs, KV*Dh), v=..., use, block_table=(nb,),
+    length)``, read through its block table."""
     cd = compute_dtype
     q = jnp.einsum("bsd,dhk->bshk", x.astype(cd), p["wq"].astype(cd))
     k = jnp.einsum("bsd,dhk->bshk", x.astype(cd), p["wk"].astype(cd))
@@ -233,24 +237,34 @@ def attention_block(
     if kv_cache is not None:
         # decode: insert this step's k/v at slot `length % T` (ring-buffer when
         # T < full context, i.e. sliding-window serving)
-        T = kv_cache["k"].shape[1]
-        slot = kv_cache["length"] % T
+        paged = "block_table" in kv_cache
+        if paged and not ops.on_tpu():
+            kv_cache, paged = _gathered_view(kv_cache), False
         cache_dt = kv_cache["k"].dtype
         new_len = kv_cache["length"] + x.shape[1]
-        if kv_cache["k"].ndim == 3:     # (B, T, KV*Dh): heads flattened
-            # the cache as it was, its slot `slot` masked, and the new
+        if paged or kv_cache["k"].ndim == 3:     # heads flattened
+            # the cache as it was, its slot `length % T` masked, and the new
             # token beside it: the cache read is never copied
             assert x.shape[1] == 1, "a flat cache decodes one token"
             k = k.reshape(*k.shape[:2], -1).astype(cache_dt)
             v = v.reshape(*v.shape[:2], -1).astype(cache_dt)
-            slot_pos = ring_slot_positions(kv_cache["length"], T)
-            slot_pos = jnp.where(jnp.arange(T) == slot, -1, slot_pos)
             sc = q.shape[-1] ** -0.5 if scale is None else scale
-            out = _flat_cache_attention(q * sc, kv_cache["k"], kv_cache["v"],
-                                        positions, slot_pos, window=window,
-                                        new=(k, v))
+            if paged:
+                out = _paged_flat_attention(q * sc, kv_cache, window=window,
+                                            new=(k, v))
+            else:
+                T = kv_cache["k"].shape[1]
+                slot_pos = ring_slot_positions(kv_cache["length"], T)
+                slot_pos = jnp.where(jnp.arange(T) == kv_cache["length"] % T,
+                                     -1, slot_pos)
+                out = _flat_cache_attention(q * sc, kv_cache["k"],
+                                            kv_cache["v"], positions,
+                                            slot_pos, window=window,
+                                            new=(k, v))
             new_kv = {"k": k, "v": v, "length": new_len}
         else:
+            T = kv_cache["k"].shape[1]
+            slot = kv_cache["length"] % T
             ck = jax.lax.dynamic_update_slice_in_dim(
                 kv_cache["k"], k.astype(cache_dt), slot, 1)
             cv = jax.lax.dynamic_update_slice_in_dim(
@@ -312,6 +326,17 @@ def _cache_mask(q_pos, slot_pos, window):
     return valid
 
 
+def _flat_query(q, F: int, dtype):
+    """Each of ``q``'s ``(B, 1, H, Dh)`` heads spread over its KV head's
+    channels of a flat ``F = KV*Dh`` row, zero elsewhere: ``(B, H, F)``
+    in ``dtype``, and those channels' mask ``(H, F)``."""
+    H, Dh = q.shape[2:]
+    mine = (jnp.arange(F)[None] // Dh
+            == (jnp.arange(H) // (H // (F // Dh)))[:, None])
+    return jnp.where(mine, jnp.tile(q[:, 0], (1, 1, F // Dh)),
+                     0).astype(dtype), mine
+
+
 def _flat_cache_attention(q, ck, cv, q_pos, slot_pos, *, window=0,
                           new=None):
     """:func:`cache_attention` of one query over a ``(B, T, KV*Dh)`` cache
@@ -323,9 +348,7 @@ def _flat_cache_attention(q, ck, cv, q_pos, slot_pos, *, window=0,
     ``(B, 1, KV*Dh)``, is attended to beside the cache."""
     B, _, H, Dh = q.shape
     T, KV = ck.shape[1], ck.shape[-1] // Dh
-    mine = (jnp.arange(KV * Dh)[None] // Dh
-            == (jnp.arange(H) // (H // KV))[:, None])       # (H, KV*Dh)
-    qf = jnp.where(mine, jnp.tile(q[:, 0], (1, 1, KV)), 0).astype(ck.dtype)
+    qf, mine = _flat_query(q, ck.shape[-1], ck.dtype)
     logits = jnp.einsum("bhf,btf->bht", qf, ck,
                         preferred_element_type=jnp.float32)
     valid = _cache_mask(q_pos, slot_pos, window)
@@ -341,6 +364,39 @@ def _flat_cache_attention(q, ck, cv, q_pos, slot_pos, *, window=0,
         out = out + probs[..., T:] * new[1].astype(jnp.float32)
     out = jnp.where(mine, out, 0).reshape(B, H, KV, Dh).sum(2)
     return out[:, None].astype(q.dtype)
+
+
+def _gathered_view(kv):
+    """One request's flat block-pool cache (see :func:`attention_block`)
+    as the dense flat cache ``(1, T, KV*Dh)`` its block table maps."""
+    table = kv["block_table"][None]
+    return {"k": gather_kv_view(kv["k"][kv["use"]], table),
+            "v": gather_kv_view(kv["v"][kv["use"]], table),
+            "length": kv["length"]}
+
+
+def _paged_flat_attention(q, kv, *, window, new):
+    """:func:`_flat_cache_attention` of ONE request (``B`` 1) over use
+    ``kv["use"]`` of flat block pools ``(uses, NB, bs, KV*Dh)``, read
+    through the request's block table ``kv["block_table"]`` ``(nb,)`` by
+    the paged kernel: only the live blocks, the pools whole, and the
+    decoded token's own ``new`` merged into its softmax statistics."""
+    kp, vp = kv["k"], kv["v"]
+    B, _, H, Dh = q.shape
+    assert B == 1, "the pools are read one request at a time"
+    F = kp.shape[-1]
+    qf, mine = _flat_query(q, F, kp.dtype)
+    m, l, acc = paged_flat_stats(qf[0], kp, vp, kv["block_table"],
+                                 kv["length"], use=kv["use"], window=window)
+    f32 = jnp.float32
+    own = jnp.einsum("hf,f->h", qf[0], new[0][0, 0],
+                     preferred_element_type=f32)
+    top = jnp.maximum(m, own)
+    a, b = jnp.exp(m - top), jnp.exp(own - top)
+    out = ((acc * a[:, None] + b[:, None] * new[1][0, 0].astype(f32))
+           / (l * a + b)[:, None])
+    out = jnp.where(mine, out, 0).reshape(H, F // Dh, Dh).sum(1)
+    return out[None, None].astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

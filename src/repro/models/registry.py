@@ -26,6 +26,10 @@ class ModelAPI:
     # decode_step takes token_kv=True: its new cache's k/v are then only
     # the token it wrote (hybrid), not whole new views
     token_kv: bool = False
+    # decode_step (with token_kv) reads a serving engine's whole KV block
+    # pools through one request's block table (cache keys k, v and
+    # block_table), not a dense view gathered for it (hybrid)
+    paged_kv: bool = False
 
 
 _FAMILY = {
@@ -101,6 +105,7 @@ def build_model(
         decode_step=decode,
         prefill=prefill,
         token_kv=cfg.family == "hybrid",
+        paged_kv=cfg.family == "hybrid",
     )
 
 

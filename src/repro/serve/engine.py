@@ -8,7 +8,10 @@ family cache from ``api.init_cache(batch=1, view_len)`` is split into:
 
   * paged leaves — the top-level attention ``k``/``v`` tensors, stored in
     a :class:`~repro.serve.paged_kv.PagedKV` block pool and materialized
-    per step as dense per-request views through the block tables;
+    per step as dense per-request views through the block tables; a
+    family whose ``api.paged_kv`` is set (the hybrid) is handed the
+    whole pools and its slot's table row instead, and reads only the
+    live blocks (``kernels/paged_attention.paged_flat_stats``);
   * opaque per-request state — everything else (SSM conv/ssd state,
     enc-dec cross KV, ...), with the slots stacked on the cache's own
     batch axis (found per leaf by comparing the cache's shapes at batch
@@ -19,25 +22,27 @@ family cache from ``api.init_cache(batch=1, view_len)`` is split into:
   * lengths — one engine-owned ``(max_active,)`` vector (per-request
     scalar under vmap), replacing the cache's scalar ``length``.
 
-One jitted step gathers the views, runs ``jax.vmap(api.decode_step)``
-with batch-1 per request, writes the token's k/v each request produced
-into its block (a family whose ``api.token_kv`` is set returns only
-those; from another's whole new view they are read at the ring slot),
-and argmaxes the next token. The step donates the KV pools, which it
-updates in place. The scan over layers writes the new state layer by
-layer into its own output buffer, so the step does not donate the old
-state: on a TPU a donated input makes XLA copy the whole new state into
-the old buffer after the loop. Admission
-writes a request's prefilled state, length and first token into its
-slot with one more jitted program, which donates what it updates and so
-writes only that slot, in place, and its prefilled KV into its blocks
-the same way (`PagedKV.write_view`). None copies the whole state or a
-whole pool.
+One jitted step gathers the views (or passes the pools and tables), runs
+``jax.vmap(api.decode_step)`` with batch-1 per request, writes the
+token's k/v each request produced into its block (a family whose
+``api.token_kv`` is set returns only those; from another's whole new
+view they are read at the ring slot), and argmaxes the next token. The
+step donates the KV pools, which it updates in place. The scan over
+layers writes the new state layer by layer into its own output buffer,
+so the step does not donate the old state: on a TPU a donated input
+makes XLA copy the whole new state into the old buffer after the loop.
+Admission writes a request's prefilled state, length and first token
+into its slot with one more jitted program, which donates what it
+updates and so writes only that slot, in place, and its prefilled KV
+into its blocks the same way (`PagedKV.write_view`). None copies the
+whole state or a whole pool.
 
 Each vmap instance is exactly the dense single-request decode — paged
 serving is therefore bit-identical to the per-request dense oracle by
 construction (the correctness tests assert this across every registry
-family).
+family). A family that reads the pools gathers its request's view
+from them off a TPU (the same numbers); on one, its paged kernel sums
+the same softmax in another order.
 
 With a mesh + ``Communicator`` the whole step runs under ``shard_map``
 and the per-token logits assembly goes through the tuned collective —
@@ -74,9 +79,12 @@ The simulated clock records the same spans. Each run also returns its
 counters (`ServeResult.counters`): ``admissions``, ``decode_steps``,
 ``gc_collections`` by generation, ``gc_s`` and ``gc_max_s`` (the
 longest single collection), with or without a profiler; and with paged
-KV, ``kv_blocks_peak`` (the most pool blocks held at once) and
-``kv_view_bytes`` (the bytes of dense KV views one decode step
-gathers).
+KV, ``kv_blocks_peak`` (the most pool blocks held at once),
+``kv_view_bytes`` (the bytes of dense KV views one decode step gathers:
+0 for a family that reads the pools) and, for such a family,
+``kv_blocks_read`` (the live table blocks the steps' attention read,
+summed over the steps: each active slot's ``ceil(min(length, view) /
+block_size)``, each block read at every use of each leaf).
 """
 from __future__ import annotations
 
@@ -176,6 +184,7 @@ class ServeEngine:
             lambda a, ax: jnp.zeros(a.shape[:ax] + (R,) + a.shape[ax:],
                                     a.dtype), opaque_tmpl, self._slot_axes)
         self.lengths = jnp.zeros((R,), jnp.int32)
+        self._host_lengths = np.zeros((R,), np.int64)   # self.lengths' copy
         self.cur_tokens = jnp.zeros((R,), jnp.int32)
         self._free_slots = list(range(R - 1, -1, -1))
         self._active_mask = np.zeros((R,), bool)
@@ -196,8 +205,9 @@ class ServeEngine:
     @property
     def kv_view_bytes(self) -> int:
         """Bytes of the dense KV views one decode step gathers: every
-        slot's whole view of every paged leaf."""
-        if self.paged is None:
+        slot's whole view of every paged leaf (none when the family reads
+        the pools)."""
+        if self.paged is None or self.api.paged_kv:
             return 0
         return self.max_active * sum(
             pool.dtype.itemsize * pool.shape[0] * self.view_len
@@ -228,9 +238,13 @@ class ServeEngine:
         comm, axes = self._comm, self._slot_axes
 
         kw = {"token_kv": True} if api.token_kv else {}
+        # what each slot's decode reads its KV from: the pools whole and
+        # its table row, or the dense view gathered for it
+        kv_axes = ({**{n: None for n in paged_names}, "block_table": 0}
+                   if api.paged_kv else 1)
 
-        def one(params, view, opq, ln, tok):
-            cache = {**opq, **view}
+        def one(params, kv, opq, ln, tok):
+            cache = {**opq, **kv}
             if has_length:
                 cache["length"] = ln
             logits, nc = api.decode_step(params, cache, tok[None, None], **kw)
@@ -244,11 +258,14 @@ class ServeEngine:
             return logits[0], written, nc, new_len
 
         def step(params, pools, tables, opaque, lengths, tokens, active):
-            views = (gather_views(pools, tables, bs) if paged_names else {})
+            if api.paged_kv:
+                kv = {**pools, "block_table": tables}
+            else:
+                kv = gather_views(pools, tables, bs) if paged_names else {}
             logits, written, new_opq, new_lens = jax.vmap(
-                one, in_axes=(None, 1, axes, 0, 0),
+                one, in_axes=(None, kv_axes, axes, 0, 0),
                 out_axes=(0, 0, axes, 0))(
-                params, views, opaque, lengths, tokens)
+                params, kv, opaque, lengths, tokens)
             if tp:
                 from repro.launch.tp_decode import logits_request
                 from repro.core.collectives.dispatch import apply_collective
@@ -331,6 +348,7 @@ class ServeEngine:
                 self.opaque, self.lengths, self.cur_tokens, slot,
                 self._opaque(cache), req.prompt_len, logits[0, -1])
         self._active_mask[slot] = True
+        self._host_lengths[slot] = req.prompt_len
         self._slot_req[slot] = req
         return slot
 
@@ -341,6 +359,16 @@ class ServeEngine:
         self._active_mask[slot] = False
         self._slot_req.pop(slot, None)
         self._free_slots.append(slot)
+
+    @property
+    def live_kv_blocks(self) -> int:
+        """Table blocks holding written tokens over the active slots: what
+        the next decode step's attention reads, for a family that reads
+        the pools (``kv_blocks_read``)."""
+        bs = self.block_size
+        live = np.minimum(self._host_lengths[self._active_mask],
+                          self.view_len)
+        return int(((live + bs - 1) // bs).sum())
 
     def step(self):
         """One decode step for every active slot. Returns {slot: token}."""
@@ -357,6 +385,7 @@ class ServeEngine:
             self.opaque = new_opq
             self.lengths = new_lens
             self.cur_tokens = jnp.where(active, next_tok, self.cur_tokens)
+            self._host_lengths[self._active_mask] += 1
         with span("serve.step.readback"):
             toks = np.asarray(next_tok)  # sync point: honest token latency
         return {s: int(toks[s]) for s in range(self.max_active)
@@ -433,6 +462,8 @@ class ServeEngine:
                                                  self.paged.blocks_held)
                 stepped = bool(sched.active)
                 if stepped:
+                    if self.api.paged_kv and self.paged is not None:
+                        counters.inc("kv_blocks_read", self.live_kv_blocks)
                     with span("serve.step", active=len(sched.active)):
                         toks = self.step()
                         if sim:

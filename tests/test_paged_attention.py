@@ -115,3 +115,119 @@ def test_table_order_matters():
     swapped = jnp.asarray(np.asarray(tables)[:, ::-1])
     perm = paged_attention(q, k_pool, v_pool, swapped, lengths, impl="xla")
     assert not np.allclose(np.asarray(base), np.asarray(perm))
+
+
+# ---------------------------------------------------------------------------
+# the hybrid's flat pools (uses, NB, bs, KV*Dh), read through a use index
+# ---------------------------------------------------------------------------
+FLAT = dict(U=2, USE=1, BS=2, NB=16, H=4, KV=2, DH=8)
+# inactive (null table), one token, a block boundary, mid-block, a ring
+# wrapped past a block edge, a ring wrapped exactly twice
+FLAT_LENGTHS = (0, 1, 4, 11, 37, 64)
+
+
+def _flat_setup(dtype, seed=0):
+    """Pools, tables (slot 0's null), lengths, the scaled queries and each
+    slot's own (k, v), with every block no slot reads (and the rows of
+    live blocks not yet written) NaN in a second copy of the pools."""
+    c = FLAT
+    R, F = len(FLAT_LENGTHS), c["KV"] * c["DH"]
+    T = c["NB"] * c["BS"]
+    num_blocks = 1 + R * c["NB"]
+    rng = np.random.default_rng(seed)
+    ids = rng.permutation(np.arange(1, num_blocks)).reshape(R, c["NB"])
+    ids[0] = 0                                     # slot 0 inactive
+    shape = (c["U"], num_blocks, c["BS"], F)
+    pools = [rng.normal(size=shape).astype(np.float32) for _ in range(2)]
+    live = np.concatenate([ids[r, :-(-min(n, T) // c["BS"])]
+                           for r, n in enumerate(FLAT_LENGTHS) if r])
+    poisoned = [p.copy() for p in pools]
+    for p in poisoned:
+        p[:, np.setdiff1d(np.arange(num_blocks), live)] = np.nan
+        for r, n in enumerate(FLAT_LENGTHS):
+            blk, off = divmod(n, c["BS"])
+            if r and n < T and off:        # unwritten rows of a live block
+                p[:, ids[r, blk], off:] = np.nan
+    q = rng.normal(size=(R, 1, 1, c["H"], c["DH"]))
+    new = [rng.normal(size=(R, 1, 1, F)) for _ in range(2)]
+    as_ = lambda a: jnp.asarray(a, dtype)          # noqa: E731
+    return (tuple(map(as_, pools)), tuple(map(as_, poisoned)),
+            jnp.asarray(ids, jnp.int32),
+            jnp.asarray(FLAT_LENGTHS, jnp.int32),
+            jnp.asarray(q, jnp.float32), tuple(map(as_, new)))
+
+
+def _flat_read(pools, tables, lengths, q, new, *, window, kernel=True):
+    """What one decode step's attention gives per slot, vmapped over the
+    slots as the serving engine does: the paged kernel (interpret mode)
+    over the whole pools, or the dense flat read of each slot's view
+    gathered through its table."""
+    def one(q, table, length, k_new, v_new):
+        kv = {"k": pools[0], "v": pools[1], "use": FLAT["USE"],
+              "block_table": table, "length": length}
+        if kernel:
+            return L._paged_flat_attention(q, kv, window=window,
+                                           new=(k_new, v_new))
+        view = L._gathered_view(kv)
+        T = view["k"].shape[1]
+        slot_pos = L.ring_slot_positions(length, T)
+        slot_pos = jnp.where(jnp.arange(T) == length % T, -1, slot_pos)
+        return L._flat_cache_attention(q, view["k"], view["v"], length[None],
+                                       slot_pos, window=window,
+                                       new=(k_new, v_new))
+
+    return jax.jit(jax.vmap(one))(q, tables, lengths, *new)
+
+
+@pytest.fixture
+def on_kernel(monkeypatch):
+    """The paged kernel evaluated in interpret mode."""
+    import functools
+    monkeypatch.setattr(L, "paged_flat_stats", functools.partial(
+        L.paged_flat_stats, interpret=True))
+
+
+# float32 pools: the kernel and the dense softmax differ in the order of
+# sums; bfloat16 pools: the kernel rounds unnormalised probabilities to
+# bfloat16 where the dense read rounds normalised ones (2**-8 relative)
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 1e-5),
+                                        (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("window", [0, 5])
+def test_flat_pool_kernel_matches_gathered_view(dtype, atol, window,
+                                                on_kernel):
+    pools, poisoned, tables, lengths, q, new = _flat_setup(dtype)
+    got = _flat_read(pools, tables, lengths, q, new, window=window)
+    # never reads a block past a slot's live count, nor a row of a live
+    # block not yet written: NaN there changes nothing
+    clean = _flat_read(poisoned, tables, lengths, q, new, window=window)
+    assert np.isfinite(np.asarray(clean)).all()
+    np.testing.assert_array_equal(np.asarray(clean), np.asarray(got))
+    want = _flat_read(pools, tables, lengths, q, new, window=window,
+                      kernel=False)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol)
+    # the inactive slot attends to its own token alone
+    c = FLAT
+    own_v = np.asarray(new[1][0, 0, 0], np.float32).reshape(c["KV"], c["DH"])
+    np.testing.assert_allclose(
+        np.asarray(got[0, 0, 0], np.float32),
+        np.repeat(own_v, c["H"] // c["KV"], axis=0), atol=atol)
+
+
+def test_flat_pool_kernel_is_one_call_over_all_slots(on_kernel):
+    """Under vmap over slots the kernel is one call, grid (slots, steps),
+    and no loop over slots; the pools go in whole, with no slice by use."""
+    pools, _, tables, lengths, q, new = _flat_setup(jnp.bfloat16)
+    jaxpr = str(jax.make_jaxpr(lambda *a: _flat_read(
+        pools, *a, window=0))(tables, lengths, q, new))
+    assert jaxpr.count("pallas_call") == 1
+    assert "while" not in jaxpr
+    R, nb = tables.shape
+    assert f"grid=({R}, {nb // 8})" in jaxpr.replace("grid_mapping=", "")
+
+
+def test_live_blocks_counts_written_blocks():
+    from repro.kernels.paged_attention import live_blocks
+    first = jnp.asarray([0, 5, 5, 5, 5, 5])
+    got = live_blocks(jnp.asarray(FLAT_LENGTHS), first, FLAT["NB"],
+                      FLAT["BS"])
+    np.testing.assert_array_equal(np.asarray(got), [0, 1, 2, 6, 16, 16])

@@ -229,6 +229,8 @@ def test_zamba2_serving_step_fits_one_chip(zamba2_engine, one_chip,
     step's own buffers fit in 16 GB; the pools are updated in place; no
     whole stored state leaf is copied or transposed."""
     import re
+    from repro.kernels import ops as kops
+    monkeypatch.setattr(kops, "on_tpu", lambda: True)
     api, params, eng, pools = zamba2_engine
     R = eng.max_active
 
@@ -253,6 +255,41 @@ def test_zamba2_serving_step_fits_one_chip(zamba2_engine, one_chip,
     for m in moves.finditer(step.as_text()):
         assert tuple(map(int, m.group(1).split(","))) not in stored, \
             m.group(0)
+
+
+def test_zamba2_decode_reads_kv_through_block_tables(zamba2_engine,
+                                                     one_chip, monkeypatch):
+    """The cell's decode step takes the paged kernel over the whole pools:
+    no use's pool is sliced out ([2049, 16, 7168]), no slot's dense view
+    gathered ([2048, 16, 7168] for all slots), the pools stay aliased, and
+    the temporaries are at least 2 GB below the 3.15 GB the gathered
+    views took."""
+    from repro.kernels import ops as kops
+    monkeypatch.setattr(kops, "on_tpu", lambda: True)
+    api, params, eng, pools = zamba2_engine
+    R = eng.max_active
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    vec = jax.ShapeDtypeStruct((R,), jnp.int32, sharding=one_chip)
+    tables = jax.ShapeDtypeStruct((R, eng.view_len // eng.block_size),
+                                  jnp.int32, sharding=one_chip)
+    step = eng._step.lower(
+        params, pools, tables, jax.tree.map(on_chip, eng.opaque), vec, vec,
+        jax.ShapeDtypeStruct((R,), jnp.bool_, sharding=one_chip)).compile()
+    txt = step.as_text()
+    calls = [line for line in txt.splitlines()
+             if KERNEL in line and "custom-call(" in line]
+    # one call per use, each over the whole pools (3 uses, 2049 blocks)
+    assert len(calls) == 3, len(calls)
+    assert all("bf16[3,2049,16,7168]" in c for c in calls)
+    assert "[2049,16,7168]" not in txt
+    assert "[2048,16,7168]" not in txt
+    mem = step.memory_analysis()
+    pool_bytes = sum(p.size * p.dtype.itemsize for p in pools.values())
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 3.15e9 - 2e9, mem
 
 
 def test_zamba2_admission_writes_kv_blocks_in_place(zamba2_engine,

@@ -114,13 +114,12 @@ def test_prefill_then_decode_matches_reference(weights):
     assert tok["k"].shape == (3, 1, 1, PROG.num_kv_heads * PROG.head_dim)
 
 
-def test_engine_with_paged_kv_serves_the_reference_argmax(weights):
-    """Served tokens through ServeEngine (paged KV, slots on the batch
-    axis): each lies within the bfloat16 KV's rounding of the
-    reference's best logit."""
+def _serve_and_check_gaps(p, prog):
+    """Serve three requests through ServeEngine (paged KV, slots on the
+    batch axis): each served token lies within the bfloat16 KV's rounding
+    of the reference's best logit."""
     from repro.serve import Scheduler, ServeEngine
     from repro.serve.scheduler import Request
-    p, prog, _, _ = weights
     api = build_model(PROG, compute_dtype=F32, attn_impl="xla",
                       ssd_impl="xla")
     rng = np.random.default_rng(5)
@@ -138,6 +137,59 @@ def test_engine_with_paged_kv_serves_the_reference_argmax(weights):
         gap = logits.max(-1) - logits[np.arange(len(r.generated)),
                                       r.generated]
         assert gap.max() < 3e-2, (r.rid, gap)
+
+
+def test_engine_with_paged_kv_serves_the_reference_argmax(weights):
+    """Off a TPU the step gathers each slot's view through its table."""
+    p, prog, _, _ = weights
+    _serve_and_check_gaps(p, prog)
+
+
+def test_engine_on_the_paged_kernel_serves_the_reference_argmax(
+        weights, monkeypatch):
+    """The TPU's path, the paged kernel in interpret mode, one call per
+    use over every slot of the engine's vmapped step."""
+    import functools
+    from repro.models import layers
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(layers, "paged_flat_stats", functools.partial(
+        layers.paged_flat_stats, interpret=True))
+    p, prog, _, _ = weights
+    _serve_and_check_gaps(p, prog)
+
+
+def test_engine_reads_pools_through_tables_counting_live_blocks(
+        weights, monkeypatch):
+    """The hybrid declares that its decode reads paged KV: the engine
+    hands it the pools and its slot's table row, never gathers a dense
+    view, and counts the live blocks each step reads: ``ceil(min(length,
+    view) / block_size)`` per active slot, a request of prompt P and n
+    tokens decoded at lengths P .. P+n-2. The ring wraps for one."""
+    from repro.serve import Scheduler, ServeEngine, engine
+    from repro.serve.scheduler import Request
+
+    def no_views(*args, **kwargs):
+        raise AssertionError("the hybrid's step gathered dense views")
+
+    monkeypatch.setattr(engine, "gather_views", no_views)
+    _, prog, _, _ = weights
+    api = build_model(PROG, compute_dtype=F32, attn_impl="xla",
+                      ssd_impl="xla")
+    assert api.paged_kv
+    view, bs = 24, 4
+    rng = np.random.default_rng(7)
+    reqs = [Request(rid=i, arrival_s=0.0, max_new=new, prompt=tuple(
+        int(t) for t in rng.integers(0, PROG.vocab_size, n)))
+        for i, (n, new) in enumerate([(5, 6), (16, 12), (8, 3)])]
+    eng = ServeEngine(api, prog, max_active=2, view_len=view, block_size=bs)
+    res = eng.run(Scheduler(reqs, max_active=2, token_budget=96),
+                  cost_model=lambda kind, n: 1e-3)
+    assert all(len(r.generated) == r.max_new for r in reqs)
+    want = sum(-(-min(length, view) // bs) for r in reqs
+               for length in range(r.prompt_len,
+                                   r.prompt_len + r.max_new - 1))
+    assert res.counters.get("kv_blocks_read") == want
+    assert res.counters.get("kv_view_bytes") == eng.kv_view_bytes == 0
 
 
 def test_grouped_ssd_kernel_matches_oracle():
@@ -216,4 +268,4 @@ def test_kv_is_held_per_use(weights):
     assert not np.any(np.asarray(cache["k"][:, :, 16:]))
     eng = ServeEngine(api, prog, max_active=2, view_len=32, block_size=8)
     assert eng.paged.pools["k"].shape == (3, 1 + 2 * 4, 8, F)
-    assert eng.kv_view_bytes == 2 * 2 * 3 * 32 * F * 2
+    assert eng.kv_view_bytes == 0           # read through the tables
