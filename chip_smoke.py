@@ -8,8 +8,9 @@ oracles on a small input; train smollm-135m at its full published width
 for a few steps; serve smollm-135m and mamba2-130m with continuous
 batching. Four chips: train smollm-135m 4-way data parallel with XLA's
 gradient all-reduce and with the tuned table, from the same init and
-batches, and compare the losses; then decode 4-way tensor parallel with
-and without the tuned table and compare the tokens.
+batches, and compare the losses; then serve one synthetic trace 4-way
+tensor parallel with and without the tuned table and compare every
+request's tokens.
 
 Everything runs in this one process, which holds the chips. Weights are
 random from a fixed seed and data is generated from a seed; the only
@@ -115,7 +116,7 @@ def train_one_chip():
 def serve_continuous(arch, requests, prompt_len, gen):
     from repro.launch import serve
 
-    res = serve.main(["--arch", arch, "--continuous",
+    res = serve.main(["--arch", arch,
                       "--num-requests", str(requests),
                       "--poisson-rate", "100", "--prompt-len",
                       str(prompt_len), "--gen", str(gen),
@@ -131,6 +132,26 @@ def serve_continuous(arch, requests, prompt_len, gen):
     print(f"serve {arch}: {s['tok_per_s']:.1f} tok/s, per-token p50 "
           f"{s['token_ms_p50']:.2f} ms p99 {s['token_ms_p99']:.2f} ms, "
           f"compiles included {LABEL}")
+
+
+def served_tokens(argv):
+    """Serve through the launcher; each finished request's tokens by id
+    (the run's records hold counts, not tokens)."""
+    from unittest import mock
+
+    import repro.serve
+    from repro.launch import serve
+
+    scheds = []
+
+    class Kept(repro.serve.Scheduler):
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            scheds.append(self)
+
+    with mock.patch.object(repro.serve, "Scheduler", Kept):
+        serve.main(argv)
+    return {r.rid: list(r.generated) for r in scheds[0].finished}
 
 
 def ssd_in_served_prefill():
@@ -155,7 +176,7 @@ def four_chips():
     import jax
     import numpy as np
 
-    from repro.launch import serve, train
+    from repro.launch import train
 
     check(len(jax.devices()) == 4, f"--four-chips needs 4 devices, have "
           f"{len(jax.devices())}")
@@ -188,15 +209,26 @@ def four_chips():
     print(f"train: tuned vs XLA sync max relative loss difference "
           f"{diff:.2e} (< 1e-3)")
     del runs
+    serve_tensor_parallel()
 
-    decode = SMOLLM + ["--batch", "4", "--prompt-len", "64", "--gen", "16",
-                       "--tensor-parallel", "4"]
-    plain = serve.main(decode)
-    tuned = serve.main(decode + ["--tuning-table", TABLE])
-    check(plain.shape == (4, 16) and np.array_equal(plain, tuned),
-          "tuned tensor-parallel decode tokens differ from XLA's")
+
+def serve_tensor_parallel():
+    """One synthetic trace served 4-way tensor parallel, with XLA's
+    collectives and with the tuned table: the same tokens per request."""
+    requests, gen = 8, 16
+    decode = SMOLLM + ["--num-requests", str(requests), "--poisson-rate",
+                       "100", "--prompt-len", "64", "--gen", str(gen),
+                       "--max-active", "4", "--tensor-parallel", "4"]
+    plain = served_tokens(decode)
+    tuned = served_tokens(decode + ["--tuning-table", TABLE])
+    check(len(plain) == requests and all(
+        len(t) == gen for t in plain.values()),
+        f"plain tensor-parallel serving finished {len(plain)} of "
+        f"{requests} requests of {gen} tokens")
+    check(plain == tuned,
+          "tuned tensor-parallel served tokens differ from XLA's")
     print(f"serve 4-way tensor parallel: tuned tokens == XLA tokens, "
-          f"{plain.shape[0]} x {plain.shape[1]}")
+          f"{requests} requests x {gen}")
 
 
 def main():

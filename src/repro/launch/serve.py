@@ -1,18 +1,13 @@
-"""Serving launcher: fixed-batch oracle + continuous batching over paged KV.
+"""Serving launcher: continuous batching over paged KV.
 
-Two modes share one model API and one tuned-collective path:
+Serves a request trace (``--request-trace`` JSONL or synthetic Poisson
+arrivals) through the ``repro.serve`` engine: paged KV blocks,
+token-budget + SLO admission, per-step join/retire.
 
-  * default (oracle) — one fixed batch prefilled in a single batched pass
-    (``api.prefill``) and greedily decoded to completion. This is the
-    validation oracle the continuous path is tested against.
-  * ``--continuous`` — the ``repro.serve`` subsystem: a request trace
-    (``--request-trace`` JSONL or synthetic Poisson arrivals), paged KV
-    blocks, token-budget + SLO admission, per-step join/retire.
-
-With ``--tensor-parallel N --tuning-table ART`` either mode's per-token
+With ``--tensor-parallel N --tuning-table ART`` the step's per-token
 logits assembly goes through the `Communicator`'s {algorithm, segments}
-choice — bit-identical to the untuned loop, but executing the tuned wire
-schedule (without ``--tuning-table`` the same loop runs XLA's own
+choice — bit-identical to the untuned step, but executing the tuned wire
+schedule (without ``--tuning-table`` the same step runs XLA's own
 collectives). Decode messages are KB-scale, so they resolve through the
 small-message end of the tuning grid; the printed decode plan is
 `Communicator.explain` over the same requests the step executes.
@@ -21,9 +16,7 @@ artifact resolves to the matching profile's table.
 
 Examples:
     python -m repro.launch.serve --arch smollm-135m --reduced \\
-        --prompt-len 32 --gen 32 --batch 4
-    python -m repro.launch.serve --arch smollm-135m --reduced \\
-        --continuous --num-requests 16 --poisson-rate 50 --slo-ms 200
+        --num-requests 16 --poisson-rate 50 --slo-ms 200
     XLA_FLAGS=--xla_force_host_platform_device_count=2 \\
         python -m repro.launch.serve --arch smollm-135m --reduced \\
         --tensor-parallel 2 --tuning-table tuned_decision.json
@@ -31,7 +24,7 @@ Examples:
 from __future__ import annotations
 
 import argparse
-import time
+import os
 
 import jax
 import jax.numpy as jnp
@@ -55,7 +48,7 @@ def _prefill_extra_fn(cfg):
     return mk
 
 
-def _serve_continuous(args, cfg, api, params, comm, mesh):
+def _serve(args, cfg, api, params, comm, mesh):
     from repro.obs import MetricsRegistry, export as obs_export
     from repro.serve import ServeEngine, Scheduler, load_trace, \
         synthetic_trace
@@ -101,7 +94,6 @@ def _serve_continuous(args, cfg, api, params, comm, mesh):
               f"{res.counters.get('kv_view_bytes'):.0f} bytes of views "
               f"gathered a step")
     if args.trace_dir:
-        import os
         os.makedirs(args.trace_dir, exist_ok=True)
         counters = MetricsRegistry().merge(res.counters)
         if comm is not None:
@@ -109,7 +101,7 @@ def _serve_continuous(args, cfg, api, params, comm, mesh):
         obs_export.write_summary(
             os.path.join(args.trace_dir, "decode_summary.json"),
             counters=counters,
-            extra={"arch": cfg.name, "mode": "continuous",
+            extra={"arch": cfg.name,
                    "tensor_parallel": args.tensor_parallel,
                    "max_active": args.max_active, "block_size": bs,
                    "view_len": view_len, "slo_ms": args.slo_ms,
@@ -119,40 +111,37 @@ def _serve_continuous(args, cfg, api, params, comm, mesh):
 
 
 def main(argv=None):
-    """Serve from CLI arguments (``argv``, default ``sys.argv[1:]``).
-    Returns the `ServeResult` with ``--continuous``, else the generated
-    tokens as a (batch, gen) array."""
+    """Serve from CLI arguments (``argv``, default ``sys.argv[1:]``);
+    returns the engine run's `ServeResult`."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m",
                     choices=sorted(ARCHITECTURES))
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--gen", type=int, default=32)
+    ap.add_argument("--prompt-len", type=int, default=32,
+                    help="longest synthetic prompt (a quarter and a half "
+                         "of it are drawn too)")
+    ap.add_argument("--gen", type=int, default=32,
+                    help="tokens generated per synthetic request")
     ap.add_argument("--window", type=int, default=0)
-    ap.add_argument("--continuous", action="store_true",
-                    help="serve a request trace with continuous batching "
-                         "over paged KV (the repro.serve subsystem) instead "
-                         "of one fixed batch")
     ap.add_argument("--request-trace", default=None,
                     help="JSONL request trace ({arrival_s, prompt_len|"
                          "prompt, max_new} per line); default: synthetic "
                          "Poisson arrivals")
     ap.add_argument("--num-requests", type=int, default=16,
-                    help="synthetic trace length (--continuous)")
+                    help="synthetic trace length")
     ap.add_argument("--poisson-rate", type=float, default=50.0,
-                    help="synthetic arrival rate, requests/s (--continuous)")
+                    help="synthetic arrival rate, requests/s")
     ap.add_argument("--slo-ms", type=float, default=None,
                     help="per-token latency SLO; admission defers prefills "
-                         "that would bust it (--continuous)")
+                         "that would bust it")
     ap.add_argument("--max-active", type=int, default=4,
-                    help="request slots decoded per step (--continuous)")
+                    help="request slots decoded per step")
     ap.add_argument("--block-size", type=int, default=16,
-                    help="KV block size in tokens (--continuous)")
+                    help="KV block size in tokens")
     ap.add_argument("--tuning-table", default=None,
                     help="tuned decision artifact (schema 2 or 3); prints "
                          "the tuned collective plan and, with "
-                         "--tensor-parallel, drives the decode loop's "
+                         "--tensor-parallel, drives the decode step's "
                          "logits collective through it")
     ap.add_argument("--tensor-parallel", type=int, default=0,
                     help=">=2: run the TP decode path over a 'model' mesh "
@@ -168,12 +157,12 @@ def main(argv=None):
                          "first-table-wins)")
     ap.add_argument("--trace-dir", default=None,
                     help="write decode_summary.json here (per-token "
-                         "latency percentiles + throughput + config; "
-                         "with --continuous also per-request records and "
-                         "the engine run's counters: admissions, decode "
-                         "steps, garbage collections and their seconds, "
-                         "KV blocks held at most and KV view bytes a "
-                         "step, which it also prints)")
+                         "latency percentiles, throughput, config, "
+                         "per-request records and the engine run's "
+                         "counters: admissions, decode steps, garbage "
+                         "collections and their seconds, KV blocks held "
+                         "at most and KV view bytes a step, which it also "
+                         "prints)")
     args = ap.parse_args(argv)
     enable_compile_cache()
     # serving places its arrays itself; a training mesh left by an earlier
@@ -186,9 +175,9 @@ def main(argv=None):
         cfg = cfg.reduced()
 
     from repro.comms import Communicator
+    from repro.serve.tp import executed_spec, tp_decode_plan
     comm = None
     if args.tuning_table:
-        from repro.launch.tp_decode import tp_decode_plan
         # the launch's single Communicator: probe -> select -> decide ->
         # dispatch (serving only dispatches with --tensor-parallel, but
         # the plan below is resolved through the same object)
@@ -198,21 +187,17 @@ def main(argv=None):
         # decode-time collectives: per-token TP all-reduce of the residual
         # (B, d) and all-gather of vocab-parallel logits (B, V/p)
         p = args.tensor_parallel or max(jax.device_count(), 2)
-        batch = args.max_active if args.continuous else args.batch
         print(f"  decode plan p={p}")
-        print(tp_decode_plan(comm, batch, cfg.d_model,
+        print(tp_decode_plan(comm, args.max_active, cfg.d_model,
                              cfg.vocab_size, p).render(indent="    "))
     api = build_model(cfg, window=args.window)
     params = api.init(jax.random.PRNGKey(0))
-    B = args.batch
-    cache_len = args.prompt_len + args.gen
 
     mesh = None
     if args.tensor_parallel >= 2:
         if comm is None:
             comm = Communicator.create()      # XLA's own collectives
         from repro import compat
-        from repro.launch.tp_decode import executed_spec
         tp = args.tensor_parallel
         if jax.device_count() < tp:
             raise SystemExit(f"{tp}-way tensor parallelism needs {tp} "
@@ -220,84 +205,14 @@ def main(argv=None):
                              "XLA_FLAGS=--xla_force_host_platform_device_"
                              f"count={tp})")
         mesh = compat.make_mesh((tp,), ("model",))
-        batch = args.max_active if args.continuous else args.batch
         nbytes, spec = executed_spec(comm, args.tp_collective,
-                                     batch, cfg.vocab_size, tp)
+                                     args.max_active, cfg.vocab_size, tp)
         how = "tuned " if args.tuning_table else ""
         print(f"tensor-parallel decode: p={tp} via {how}"
               f"{args.tp_collective} ({nbytes} B -> {spec.algorithm} "
               f"segments={spec.segments})")
 
-    if args.continuous:
-        return _serve_continuous(args, cfg, api, params, comm, mesh)
-
-    # ---- fixed-batch validation oracle ----------------------------------
-    if mesh is not None:
-        from repro.launch.tp_decode import build_tp_decode_step
-        step = build_tp_decode_step(api, mesh, comm,
-                                    collective=args.tp_collective)
-    else:
-        step = jax.jit(api.decode_step)
-
-    rng = np.random.default_rng(0)
-    prompt = jnp.asarray(rng.integers(0, cfg.vocab_size,
-                                      (B, args.prompt_len)), jnp.int32)
-
-    # one real batched prefill pass (the same path the scheduler uses)
-    extra = {}
-    if cfg.family == "encdec":
-        extra["audio"] = jnp.asarray(
-            rng.normal(size=(B, cfg.encoder_seq, cfg.d_model)), jnp.bfloat16)
-    t0 = time.time()
-    logits, cache = api.prefill(params, prompt, cache_len, **extra)
-    logits = logits[:, -1]
-    jax.block_until_ready(logits)
-    t_prefill = time.time() - t0
-
-    out = []
-    tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-    # per-token latency: each token is synced before the next issues, so
-    # the percentiles are honest tail latencies (the number a serving
-    # SLO watches), not async dispatch times
-    tok_ms = []
-    t0 = time.time()
-    for _ in range(args.gen):
-        out.append(tok)
-        tt0 = time.perf_counter()
-        logits, cache = step(params, cache, tok)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-        jax.block_until_ready(tok)
-        tok_ms.append((time.perf_counter() - tt0) * 1e3)
-    t_gen = time.time() - t0
-
-    gen = jnp.concatenate(out, axis=1)
-    p50, p90, p99 = np.percentile(tok_ms, [50, 90, 99])
-    print(f"arch={cfg.name} batch={B} prompt={args.prompt_len} "
-          f"gen={args.gen}")
-    print(f"prefill: {t_prefill:.2f}s  decode: {t_gen:.2f}s "
-          f"({B * args.gen / t_gen:.1f} tok/s)")
-    print(f"per-token decode latency: p50 {p50:.2f} ms  "
-          f"p90 {p90:.2f} ms  p99 {p99:.2f} ms")
-    print("sample tokens:", np.asarray(gen[0, :16]).tolist())
-
-    if args.trace_dir:
-        import os
-
-        from repro.obs import export as obs_export
-        os.makedirs(args.trace_dir, exist_ok=True)
-        obs_export.write_summary(
-            os.path.join(args.trace_dir, "decode_summary.json"),
-            counters=comm.metrics if comm is not None else None,
-            extra={"arch": cfg.name, "batch": B,
-                   "prompt_len": args.prompt_len, "gen": args.gen,
-                   "tensor_parallel": args.tensor_parallel,
-                   "prefill_s": t_prefill, "decode_s": t_gen,
-                   "tok_per_s": B * args.gen / t_gen,
-                   "token_ms_p50": float(p50),
-                   "token_ms_p90": float(p90),
-                   "token_ms_p99": float(p99)})
-        print(f"decode summary -> {args.trace_dir}/decode_summary.json")
-    return np.asarray(gen)
+    return _serve(args, cfg, api, params, comm, mesh)
 
 
 if __name__ == "__main__":

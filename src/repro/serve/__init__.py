@@ -7,13 +7,17 @@ Layering (bottom to top):
   * ``scheduler``  — pure-python continuous-batching policy: arrival
                      queue, token-budget admission, SLO-aware
                      prefill/decode interleave, mid-flight join/retire.
+  * ``tp``         — the tensor-parallel logits assembly through the
+                     tuned ``Communicator``, and the requests its plan
+                     renders.
   * ``engine``     — JAX execution: per-family prefill + vmapped decode
-                     over fixed request slots, paged KV views, tuned TP
-                     decode collectives via ``Communicator``, and
-                     per-request latency records for ``decode_summary``.
+                     over fixed request slots, paged KV reads, the
+                     ``tp`` assembly under a mesh, and per-request
+                     latency records for ``decode_summary``.
 
-``launch/serve.py`` is a thin CLI over this package; the fixed-batch
-path there remains the validation oracle for everything here.
+``launch/serve.py`` is a thin CLI over this package. The engine's
+correctness oracle is the model's own dense ``decode_step``, one request
+at a time (``tests/test_serving.py``).
 """
 from repro.serve.paged_kv import BlockPool, PagedKV
 from repro.serve.scheduler import Request, Scheduler, load_trace, \

@@ -45,12 +45,12 @@ from them off a TPU (the same numbers); on one, its paged kernel sums
 the same softmax in another order.
 
 With a mesh + ``Communicator`` the whole step runs under ``shard_map``
-and the per-token logits assembly goes through the tuned collective —
-the same masked-all_reduce / transposed-all_gather construction as
-``launch.tp_decode.build_tp_decode_step``, built from the same request
-objects ``Communicator.explain`` renders, so the reported decode plan is
-exactly the executed plan. Decode logits at serving batch sizes are
-KB-scale messages: the small-message end of the tuning grid.
+and the per-token logits assembly goes through the tuned collective
+(``serve.tp.assemble_logits``: masked all_reduce or transposed
+all_gather), built from the same request objects ``Communicator.explain``
+renders, so the reported decode plan is exactly the executed plan.
+Decode logits at serving batch sizes are KB-scale messages: the
+small-message end of the tuning grid.
 
 The run loop's clock: with ``cost_model=None`` it is the wall clock; a
 ``cost_model(kind, n) -> seconds`` callable switches every duration (and
@@ -100,6 +100,7 @@ import numpy as np
 
 from repro.obs import MetricsRegistry, span
 from repro.serve.paged_kv import PagedKV, gather_views, write_tokens
+from repro.serve.tp import assemble_logits, decode_requests
 
 PAGED_LEAVES = ("k", "v")
 
@@ -223,7 +224,6 @@ class ServeEngine:
     def decode_requests(self):
         """The decode-step collective requests (for ``explain()``) — same
         builders as the executed step, batch = the slot count."""
-        from repro.launch.tp_decode import decode_requests
         cfg = self.api.cfg
         return decode_requests(self.max_active, cfg.d_model, cfg.vocab_size,
                                max(self._tp, 2), axis=self._axis)
@@ -231,7 +231,7 @@ class ServeEngine:
     # -- jitted step -------------------------------------------------------
 
     def _build_step(self):
-        api, R = self.api, self.max_active
+        api = self.api
         T, bs = self.view_len, self.block_size
         paged_names, has_length = self.paged_names, self._has_length
         tp, ax, collective = self._tp, self._axis, self._collective
@@ -261,34 +261,13 @@ class ServeEngine:
             if api.paged_kv:
                 kv = {**pools, "block_table": tables}
             else:
-                kv = gather_views(pools, tables, bs) if paged_names else {}
+                kv = gather_views(pools, tables) if paged_names else {}
             logits, written, new_opq, new_lens = jax.vmap(
                 one, in_axes=(None, kv_axes, axes, 0, 0),
                 out_axes=(0, 0, axes, 0))(
                 params, kv, opaque, lengths, tokens)
             if tp:
-                from repro.launch.tp_decode import logits_request
-                from repro.core.collectives.dispatch import apply_collective
-                V = logits.shape[-1]
-                assert V % tp == 0, f"vocab {V} not divisible by tp={tp}"
-                shard = V // tp
-                r = jax.lax.axis_index(ax)
-                req = logits_request(collective, R, V, tp, axis=ax,
-                                     itemsize=logits.dtype.itemsize,
-                                     dtype=str(logits.dtype))
-                spec = comm.spec(req)
-                if collective == "all_gather":
-                    own = jax.lax.dynamic_slice_in_dim(
-                        logits, r * shard, shard, axis=-1)
-                    logits = apply_collective("all_gather", own.T, ax, tp,
-                                              spec).T
-                else:
-                    cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
-                                                    logits.ndim - 1)
-                    masked = jnp.where(cols // shard == r, logits,
-                                       jnp.zeros_like(logits))
-                    logits = apply_collective("all_reduce", masked, ax, tp,
-                                              spec)
+                logits = assemble_logits(logits, comm, collective, ax, tp)
             pos = lengths % T
             new_pools = (write_tokens(pools, tables, written, pos, bs)
                          if paged_names else pools)

@@ -27,8 +27,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention import gather_kv_view
 
-def gather_views(pools, tables, block_size: int):
+
+def gather_views(pools, tables):
     """The dense per-request views, jit-friendly.
 
     pools: leaf -> (lead, NB, bs, ...); tables: (R, nb) int32.
@@ -37,15 +39,11 @@ def gather_views(pools, tables, block_size: int):
     puts them, so the result vmaps over slots (``in_axes=1``) with no
     transpose of the views.
     """
-    out = {}
-    R, nb = tables.shape
-    for name, pool in pools.items():
-        # one gather of whole blocks per lead index: on a TPU a gather
-        # under the lead axis puts the requests first and transposes
-        v = jnp.stack([pool[i][tables] for i in range(pool.shape[0])])
-        v = v.reshape(v.shape[0], R, nb * block_size, *v.shape[4:])
-        out[name] = v[:, :, None]                  # (lead, R, 1, T, ...)
-    return out
+    # one gather of whole blocks per lead index: on a TPU a gather under
+    # the lead axis puts the requests first and transposes
+    return {name: jnp.stack([gather_kv_view(pool[i], tables)
+                             for i in range(pool.shape[0])])[:, :, None]
+            for name, pool in pools.items()}   # (lead, R, 1, T, ...)
 
 
 def write_tokens(pools, tables, tokens, positions, block_size: int):

@@ -1,11 +1,18 @@
-"""Validate the serving engine's tuned tensor-parallel decode path on 2
-simulated devices: the continuous-batching engine driving its logits
-collective through the committed decision artifact must generate tokens
+"""Validate the serving engine's tensor-parallel decode path on 2
+simulated devices.
+
+Default: the continuous-batching engine driving its logits collective
+through the committed decision artifact must generate tokens
 BIT-IDENTICAL to the per-request dense (single-program) oracle, for both
 TP collectives — and the decode-plan requests must resolve through the
 KB-scale (small-message) end of the tuned grid, with an algorithm choice
-that differs from the MB training regime. Prints OK/FAIL lines and
-``FAILS: n``; exit 1 on any FAIL.
+that differs from the MB training regime.
+
+``--static``: each TP collective under several static algorithms; every
+decode step's logits must be BIT-IDENTICAL to the engine's without a
+mesh, and the tokens to the oracle's.
+
+Prints OK/FAIL lines and ``FAILS: n``; exit 1 on any FAIL.
 """
 import os
 import sys
@@ -19,6 +26,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 from repro import compat
 from repro.comms import CollectiveRequest, Communicator
 from repro.configs import get_config
+from repro.core.collectives.dispatch import CollectiveSpec
 from repro.models.registry import build_model
 from repro.serve import ServeEngine, Scheduler, synthetic_trace
 
@@ -54,17 +62,51 @@ def oracle(req):
     return out
 
 
-want = {r.rid: oracle(r) for r in trace()}
-
-fails = []
-for collective in ("all_gather", "all_reduce"):
+def serve(**engine_kw):
+    """Serve the trace on the simulated clock; the tokens per request and
+    the logits of every decode step."""
     engine = ServeEngine(api, params, max_active=MAX_ACTIVE, view_len=VIEW,
-                         block_size=BLOCK, mesh=mesh, comm=comm,
-                         collective=collective)
+                         block_size=BLOCK, **engine_kw)
+    step, logits = engine._step, []
+
+    def recording(*args):
+        out = step(*args)
+        logits.append(np.asarray(out[0]))
+        return out
+
+    engine._step = recording
     sched = Scheduler(trace(), max_active=MAX_ACTIVE,
                       token_budget=MAX_ACTIVE * VIEW)
     engine.run(sched, cost_model=lambda kind, n: 1e-3)
-    got = {r.rid: list(r.generated) for r in sched.finished}
+    return engine, {r.rid: list(r.generated) for r in sched.finished}, \
+        np.stack(logits)
+
+
+want = {r.rid: oracle(r) for r in trace()}
+
+fails = []
+if "--static" in sys.argv[1:]:
+    _, _, ref = serve()
+    for collective, algo in [("all_gather", "xla"), ("all_gather", "ring"),
+                             ("all_gather", "bruck"),
+                             ("all_reduce", "xla"), ("all_reduce", "ring"),
+                             ("all_reduce", "recursive_doubling"),
+                             ("all_reduce", "rabenseifner")]:
+        _, got, logits = serve(
+            mesh=mesh, comm=Communicator.create(
+                mesh, static=CollectiveSpec(algo, 1)),
+            collective=collective)
+        identical = bool(got == want and logits.shape == ref.shape
+                         and (logits == ref).all())
+        print(("OK  " if identical else "FAIL"),
+              f"serve_tp/{collective}/{algo} bit-identical={identical}")
+        if not identical:
+            fails.append((collective, algo))
+    print(f"FAILS: {len(fails)}")
+    sys.exit(1 if fails else 0)
+
+for collective in ("all_gather", "all_reduce"):
+    engine, got, _ = serve(mesh=mesh, comm=comm, collective=collective)
     identical = got == want
     print(("OK  " if identical else "FAIL"),
           f"serve_tp/{collective} bit-identical={identical}")

@@ -221,7 +221,7 @@ def test_probe_with_fabricless_artifact_falls_back_to_first_table(tmp_path):
 # explain: the plan is the executed lookup
 # ---------------------------------------------------------------------------
 def test_explain_matches_tp_decode_executed_spec(tmp_path):
-    from repro.launch.tp_decode import (
+    from repro.serve.tp import (
         decode_requests,
         executed_spec,
         logits_request,

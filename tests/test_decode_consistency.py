@@ -157,10 +157,10 @@ def test_encdec_decode_matches_teacher_forcing():
 # tuned tensor-parallel decode (serving consumes the decision artifact)
 # ---------------------------------------------------------------------------
 def test_tp_decode_bit_identical_2dev():
-    """The tuned TP decode path (vocab-parallel all-gather and partial-sum
-    all-reduce, each under several tuned algorithms) produces logits
-    BIT-identical to the plain untuned decode loop. Multi-device, so it
-    runs the helper as a subprocess."""
+    """The serving engine's tuned TP decode (vocab-parallel all-gather and
+    partial-sum all-reduce, each under several static algorithms) produces
+    logits BIT-identical to the engine without a mesh at every step.
+    Multi-device, so it runs the helper as a subprocess."""
     import os
     import subprocess
     import sys
@@ -169,7 +169,7 @@ def test_tp_decode_bit_identical_2dev():
     env["PYTHONPATH"] = os.path.join(here, "..", "src")
     r = subprocess.run(
         [sys.executable, os.path.join(here, "helpers",
-                                      "validate_tp_decode.py")],
+                                      "validate_serve_tp.py"), "--static"],
         env=env, capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, \
         f"STDOUT:\n{r.stdout[-4000:]}\nERR:\n{r.stderr[-2000:]}"
@@ -177,33 +177,45 @@ def test_tp_decode_bit_identical_2dev():
 
 
 def test_tp_decode_single_device_wiring():
-    """In-process sanity at p=1: the tuned TP step is exactly the plain
-    step (gather of the only shard / sum of one partial), so the wiring
-    itself cannot perturb logits."""
+    """In-process sanity at p=1: the engine's TP step, with XLA's
+    collectives and with a static tuned spec, is exactly the engine's
+    step without a mesh (gather of the only shard / sum of one partial),
+    so the wiring itself cannot perturb logits."""
     from repro import compat
     from repro.comms import Communicator
     from repro.configs import get_config
     from repro.core.collectives.dispatch import CollectiveSpec
-    from repro.launch.tp_decode import build_tp_decode_step
     from repro.models.registry import build_model
+    from repro.serve import Scheduler, ServeEngine, synthetic_trace
 
     cfg = get_config("smollm-135m").reduced()
     api = build_model(cfg, attn_impl="xla")
     params = api.init(jax.random.PRNGKey(0))
     mesh = compat.make_mesh((1,), ("model",))
-    B, S = 2, 5
-    tokens = jax.random.randint(KEY, (B, S), 0, cfg.vocab_size)
 
-    plain = jax.jit(api.decode_step)
+    def serve(**kw):
+        eng = ServeEngine(api, params, max_active=2, view_len=12,
+                          block_size=4, **kw)
+        step, logits = eng._step, []
+
+        def recording(*args):
+            out = step(*args)
+            logits.append(np.asarray(out[0]))
+            return out
+
+        eng._step = recording
+        eng.run(Scheduler(synthetic_trace(3, rate_rps=500.0,
+                                          vocab=cfg.vocab_size,
+                                          prompt_lens=(3, 5), max_new=5,
+                                          seed=0),
+                          max_active=2, token_budget=24),
+                cost_model=lambda kind, n: 1e-3)
+        return np.stack(logits)
+
+    plain = serve()
     for collective in ("all_gather", "all_reduce"):
-        step = build_tp_decode_step(
-            api, mesh, Communicator.create(
-                mesh, static=CollectiveSpec("ring", 1)),
-            collective=collective)
-        cache_a = api.init_cache(B, S)
-        cache_b = api.init_cache(B, S)
-        for i in range(S):
-            la, cache_a = plain(params, cache_a, tokens[:, i:i + 1])
-            lb, cache_b = step(params, cache_b, tokens[:, i:i + 1])
-            assert (np.asarray(la) == np.asarray(lb)).all(), \
-                f"{collective} pos {i}"
+        for comm in (Communicator.create(mesh), Communicator.create(
+                mesh, static=CollectiveSpec("ring", 1))):
+            got = serve(mesh=mesh, comm=comm, collective=collective)
+            assert got.shape == plain.shape and (got == plain).all(), \
+                f"{collective} {comm.describe()}"

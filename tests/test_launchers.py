@@ -81,30 +81,13 @@ def test_train_cli_tuned_collective_8dev():
     assert "collective=ring" in r.stdout
 
 
-def test_serve_cli(tmp_path):
-    import json as _json
-    r = _run(["repro.launch.serve", "--arch", "smollm-135m", "--reduced",
-              "--batch", "2", "--prompt-len", "8", "--gen", "8",
-              "--trace-dir", str(tmp_path)])
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "tok/s" in r.stdout
-    # per-token decode latency percentiles (each token synced, so the
-    # numbers are honest tail latencies)
-    assert "per-token decode latency: p50" in r.stdout
-    assert "p99" in r.stdout
-    doc = _json.loads((tmp_path / "decode_summary.json").read_text())
-    assert doc["gen"] == 8
-    assert doc["token_ms_p50"] <= doc["token_ms_p99"]
-    assert doc["tok_per_s"] > 0
-
-
 def test_serve_cli_tp_tuned_2dev():
-    """Serving consumes the decision artifact in the decode loop (not just
+    """Serving consumes the decision artifact in the decode step (not just
     the printed plan) via the tuned tensor-parallel path."""
     art = os.path.join(HERE, "..", "examples", "artifacts",
                        "tuned_decision.json")
     r = _run(["repro.launch.serve", "--arch", "smollm-135m", "--reduced",
-              "--batch", "2", "--prompt-len", "8", "--gen", "8",
+              "--prompt-len", "8", "--gen", "8",
               "--tensor-parallel", "2", "--tuning-table", art],
              xla_devices=2)
     assert r.returncode == 0, r.stdout + r.stderr
@@ -113,20 +96,29 @@ def test_serve_cli_tp_tuned_2dev():
     assert "tok/s" in r.stdout
 
 
-def test_serve_cli_continuous(tmp_path):
-    """--continuous: Poisson trace through the repro.serve subsystem,
-    per-request spans exported next to the run summary."""
+@pytest.mark.parametrize("slo", [None, "5000"], ids=["no_slo", "slo"])
+def test_serve_cli(tmp_path, slo):
+    """Poisson trace through the repro.serve engine, per-request spans
+    exported next to the run summary; with --slo-ms, the p99 check."""
     import json as _json
     r = _run(["repro.launch.serve", "--arch", "smollm-135m", "--reduced",
-              "--continuous", "--num-requests", "6", "--poisson-rate",
+              "--num-requests", "6", "--poisson-rate",
               "200", "--prompt-len", "8", "--gen", "6",
               "--max-active", "2", "--block-size", "4",
-              "--trace-dir", str(tmp_path)])
+              "--trace-dir", str(tmp_path)]
+             + (["--slo-ms", slo] if slo else []))
     assert r.returncode == 0, r.stdout + r.stderr
     assert "continuous serving: arch=smollm-135m requests=6" in r.stdout
     assert "served 6 requests" in r.stdout
+    # per-token decode latency percentiles (each token synced, so the
+    # numbers are honest tail latencies)
+    assert "per-token decode latency: p50" in r.stdout
+    assert ("SLO p99 <= 5000 ms: met" in r.stdout) == bool(slo)
     doc = _json.loads((tmp_path / "decode_summary.json").read_text())
-    assert doc["mode"] == "continuous"
+    assert "mode" not in doc
+    assert doc["slo_ms"] == (float(slo) if slo else None)
+    assert doc["token_ms_p50"] <= doc["token_ms_p99"]
+    assert doc["tok_per_s"] > 0
     assert doc["requests"] and len(doc["requests"]) == 6
     for rec in doc["requests"]:
         assert rec["new_tokens"] == 6
@@ -144,7 +136,7 @@ def test_serve_cli_continuous_tp_slo_8dev(tmp_path):
     art = os.path.join(HERE, "..", "examples", "artifacts",
                        "tuned_decision.json")
     r = _run(["repro.launch.serve", "--arch", "smollm-135m", "--reduced",
-              "--continuous", "--num-requests", "6", "--poisson-rate",
+              "--num-requests", "6", "--poisson-rate",
               "200", "--prompt-len", "8", "--gen", "6",
               "--max-active", "2", "--block-size", "4",
               "--slo-ms", "4000",
@@ -158,7 +150,7 @@ def test_serve_cli_continuous_tp_slo_8dev(tmp_path):
     assert "served 6 requests" in r.stdout
     assert "SLO p99 <=" in r.stdout
     doc = _json.loads((tmp_path / "decode_summary.json").read_text())
-    assert doc["mode"] == "continuous" and doc["tensor_parallel"] == 2
+    assert doc["tensor_parallel"] == 2
     assert doc["slo_ms"] == 4000.0
     assert len(doc["requests"]) == 6
 

@@ -1,120 +1,120 @@
-"""Block-table (paged) decode attention: the XLA gather fallback must
-match the dense ring-buffer attention of ``models/layers`` on the
-equivalent view, and the Pallas kernel body (``interpret=True`` on CPU)
-must match the fallback — including wrapped (evicted-and-refilled)
-views and sliding windows."""
+"""Paged decode reads. The dense families' read — the views gathered
+through the block tables (``serve/paged_kv.gather_views``), then the
+ring-buffer ``models/layers.cache_attention`` — must match the same
+attention over a view built slot by slot, including wrapped
+(evicted-and-refilled) views and sliding windows. The hybrid's flat-pool
+kernel (``interpret=True`` on CPU) must match its gathered view."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.paged_attention import (
-    gather_kv_view,
-    paged_attention,
-    ring_slot_positions,
-)
 from repro.models import layers as L
+from repro.serve.paged_kv import gather_views
 
 R, NB_PER_REQ, BS, KV, H, DH = 3, 3, 4, 2, 4, 8
+LEAD = 2                                  # stacked layers per pool
 T = NB_PER_REQ * BS                       # logical view length (12)
 
 
 def _setup(seed=0):
     rng = np.random.default_rng(seed)
     num_blocks = 1 + R * NB_PER_REQ
-    k_pool = jnp.asarray(rng.normal(size=(num_blocks, BS, KV, DH)),
-                         jnp.float32)
-    v_pool = jnp.asarray(rng.normal(size=(num_blocks, BS, KV, DH)),
-                         jnp.float32)
+    pools = {n: jnp.asarray(rng.normal(size=(LEAD, num_blocks, BS, KV, DH)),
+                            jnp.float32) for n in ("k", "v")}
     # shuffled non-contiguous tables: block order must matter
     ids = rng.permutation(np.arange(1, num_blocks))
     tables = jnp.asarray(ids.reshape(R, NB_PER_REQ), jnp.int32)
     q = jnp.asarray(rng.normal(size=(R, 1, H, DH)), jnp.float32)
-    return q, k_pool, v_pool, tables
+    return q, pools, tables
 
 
-def _dense_reference(q, k_pool, v_pool, tables, lengths, *, window=0):
-    """Per-request ``cache_attention`` on the gathered dense view."""
-    ck = gather_kv_view(k_pool, tables)
-    cv = gather_kv_view(v_pool, tables)
-    outs = []
-    for r in range(q.shape[0]):
-        lr = int(lengths[r])
-        out = L.cache_attention(
-            q[r:r + 1], ck[r:r + 1], cv[r:r + 1],
-            jnp.asarray([lr - 1]),
-            L.ring_slot_positions(jnp.int32(lr), T), window=window)
-        outs.append(out)
-    return jnp.concatenate(outs, axis=0)
+def _by_hand(pool, tables, r):
+    """Request ``r``'s dense view ``(LEAD, T, KV, DH)``, slot by slot."""
+    pool, tables = np.asarray(pool), np.asarray(tables)
+    return np.stack([pool[:, tables[r, s // BS], s % BS] for s in range(T)],
+                    axis=1)
+
+
+def _attend(q, ck, cv, length, window=0):
+    """``cache_attention`` of one request whose ``length`` tokens, the
+    query's included, are in the ring view ``ck``/``cv`` ``(T, KV, DH)``."""
+    return L.cache_attention(q[None], jnp.asarray(ck)[None],
+                             jnp.asarray(cv)[None],
+                             jnp.asarray([length - 1]),
+                             L.ring_slot_positions(jnp.int32(length), T),
+                             window=window)[0]
+
+
+def _gathered_read(q, pools, tables, lengths, window=0):
+    """The dense families' paged read: every layer of every request."""
+    views = gather_views(pools, tables)          # (LEAD, R, 1, T, KV, DH)
+    return np.stack([[np.asarray(_attend(q[r], views["k"][i, r, 0],
+                                         views["v"][i, r, 0],
+                                         int(lengths[r]), window))
+                      for r in range(R)] for i in range(LEAD)])
+
+
+def _by_hand_read(q, pools, tables, lengths, window=0):
+    ks = [_by_hand(pools["k"], tables, r) for r in range(R)]
+    vs = [_by_hand(pools["v"], tables, r) for r in range(R)]
+    return np.stack([[np.asarray(_attend(q[r], ks[r][i], vs[r][i],
+                                         int(lengths[r]), window))
+                      for r in range(R)] for i in range(LEAD)])
 
 
 def test_ring_slot_positions_matches_model_layer():
+    """Each slot holds the last position written to it, -1 if none."""
     for length in (0, 1, 5, T, T + 5, 3 * T + 1):
+        want = [-1] * T
+        for pos in range(length):
+            want[pos % T] = pos
         np.testing.assert_array_equal(
-            np.asarray(ring_slot_positions(jnp.int32(length), T)),
-            np.asarray(L.ring_slot_positions(jnp.int32(length), T)))
+            np.asarray(L.ring_slot_positions(jnp.int32(length), T)), want)
 
 
 @pytest.mark.parametrize("window", [0, 6])
 def test_xla_matches_dense_cache_attention(window):
-    q, k_pool, v_pool, tables = _setup()
+    q, pools, tables = _setup()
     # partial, full, and wrapped (ring eviction/refill) views
     lengths = jnp.asarray([5, T, T + 5], jnp.int32)
-    got = paged_attention(q, k_pool, v_pool, tables, lengths,
-                          window=window, impl="xla")
-    ref = _dense_reference(q, k_pool, v_pool, tables, lengths,
-                           window=window)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=1e-6, rtol=1e-6)
-
-
-@pytest.mark.parametrize("window", [0, 6])
-def test_pallas_interpret_matches_xla(window):
-    q, k_pool, v_pool, tables = _setup(seed=1)
-    lengths = jnp.asarray([5, T, T + 5], jnp.int32)
-    xla = paged_attention(q, k_pool, v_pool, tables, lengths,
-                          window=window, impl="xla")
-    pallas = paged_attention(q, k_pool, v_pool, tables, lengths,
-                             window=window, impl="pallas", interpret=True)
-    np.testing.assert_allclose(np.asarray(pallas), np.asarray(xla),
-                               atol=2e-6, rtol=2e-6)
+    got = _gathered_read(q, pools, tables, lengths, window)
+    want = _by_hand_read(q, pools, tables, lengths, window)
+    np.testing.assert_array_equal(got, want)
 
 
 def test_pallas_interpret_wrapped_view():
     """A view several wraps deep (every block evicted and refilled more
-    than once) still agrees across implementations."""
-    q, k_pool, v_pool, tables = _setup(seed=2)
+    than once) reads the same through the tables as the attention over
+    the tokens each slot last held, by a plain softmax."""
+    q, pools, tables = _setup(seed=2)
     lengths = jnp.asarray([2 * T + 3, 3 * T, T + 1], jnp.int32)
-    xla = paged_attention(q, k_pool, v_pool, tables, lengths, impl="xla")
-    pallas = paged_attention(q, k_pool, v_pool, tables, lengths,
-                             impl="pallas", interpret=True)
-    ref = _dense_reference(q, k_pool, v_pool, tables, lengths)
-    np.testing.assert_allclose(np.asarray(xla), np.asarray(ref),
-                               atol=1e-6, rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(pallas), np.asarray(xla),
-                               atol=2e-6, rtol=2e-6)
-
-
-def test_auto_impl_picks_xla_off_tpu():
-    q, k_pool, v_pool, tables = _setup()
-    lengths = jnp.asarray([5, 7, 9], jnp.int32)
-    if jax.default_backend() == "tpu":
-        pytest.skip("auto resolves to pallas on TPU")
-    auto = paged_attention(q, k_pool, v_pool, tables, lengths, impl="auto")
-    xla = paged_attention(q, k_pool, v_pool, tables, lengths, impl="xla")
-    np.testing.assert_array_equal(np.asarray(auto), np.asarray(xla))
+    got = _gathered_read(q, pools, tables, lengths)
+    want = np.empty_like(got)
+    g = H // KV
+    for r in range(R):
+        k = _by_hand(pools["k"], tables, r)      # every slot written
+        v = _by_hand(pools["v"], tables, r)
+        qr = np.asarray(q[r, 0]).reshape(KV, g, DH) * DH ** -0.5
+        for i in range(LEAD):
+            s = np.einsum("kgd,tkd->kgt", qr, k[i])
+            p = np.exp(s - s.max(-1, keepdims=True))
+            p /= p.sum(-1, keepdims=True)
+            want[i, r, 0] = np.einsum("kgt,tkd->kgd", p,
+                                      v[i]).reshape(H, DH)
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
 
 
 def test_table_order_matters():
     """Swapping two blocks in a table permutes the view — the attention
     output over a PARTIAL view must change (guards against gathers that
     ignore table order)."""
-    q, k_pool, v_pool, tables = _setup(seed=3)
+    q, pools, tables = _setup(seed=3)
     lengths = jnp.asarray([6, 6, 6], jnp.int32)   # second block half-full
-    base = paged_attention(q, k_pool, v_pool, tables, lengths, impl="xla")
+    base = _gathered_read(q, pools, tables, lengths)
     swapped = jnp.asarray(np.asarray(tables)[:, ::-1])
-    perm = paged_attention(q, k_pool, v_pool, swapped, lengths, impl="xla")
-    assert not np.allclose(np.asarray(base), np.asarray(perm))
+    perm = _gathered_read(q, pools, swapped, lengths)
+    assert not np.allclose(base, perm)
 
 
 # ---------------------------------------------------------------------------

@@ -80,7 +80,7 @@ def test_paged_kv_write_gather_roundtrip():
                             jnp.float32) for n in ("k", "v")}
     kv.write_view(0, views)
     # the views come back with the slots on the batch axis: (lead, R, 1, T, ..)
-    got = gather_views(kv.pools, kv.tables, bs)
+    got = gather_views(kv.pools, kv.tables)
     for n in ("k", "v"):
         np.testing.assert_array_equal(np.asarray(got[n][:, 0]),
                                       np.asarray(views[n]))
@@ -89,7 +89,7 @@ def test_paged_kv_write_gather_roundtrip():
            for n in ("k", "v")}
     kv.pools = write_tokens(kv.pools, kv.tables, tok,
                             jnp.asarray([5, 0], jnp.int32), bs)
-    got = gather_views(kv.pools, kv.tables, bs)
+    got = gather_views(kv.pools, kv.tables)
     for n in ("k", "v"):
         np.testing.assert_array_equal(np.asarray(got[n][:, 0, 0, 5]),
                                       np.asarray(tok[n][0]))
@@ -648,8 +648,7 @@ def test_engine_kv_counters_match_pool_and_views(tiny_engine):
     kv = engine.paged
     assert res.counters.get("kv_blocks_peak") == 2 * kv.blocks_per_request
     assert kv.blocks_held == 0                 # every request retired
-    views = jax.eval_shape(lambda: gather_views(kv.pools, kv.tables,
-                                                kv.block_size))
+    views = jax.eval_shape(lambda: gather_views(kv.pools, kv.tables))
     assert res.counters.get("kv_view_bytes") == engine.kv_view_bytes == sum(
         v.size * v.dtype.itemsize for v in views.values())
 

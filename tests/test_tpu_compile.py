@@ -19,7 +19,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
-from repro.kernels.paged_attention import paged_attention
 
 KERNEL = "tpu_custom_call"
 
@@ -119,17 +118,6 @@ def test_segment_combine_compiles(one_chip):
     fn = functools.partial(ops.segment_combine, op="add", impl="pallas")
     txt = _compile_text(fn, one_chip, ((n,), jnp.float32),
                         ((n,), jnp.float32))
-    assert KERNEL in txt
-
-
-def test_paged_attention_compiles(one_chip):
-    H, KV, D = SMOLLM["H"], SMOLLM["KV"], SMOLLM["D"]
-    R, bs, nb = 8, 16, 8
-    fn = functools.partial(paged_attention, impl="pallas")
-    txt = _compile_text(fn, one_chip, ((R, 1, H, D), jnp.bfloat16),
-                        ((R * nb, bs, KV, D), jnp.bfloat16),
-                        ((R * nb, bs, KV, D), jnp.bfloat16),
-                        ((R, nb), jnp.int32), ((R,), jnp.int32))
     assert KERNEL in txt
 
 
@@ -367,3 +355,44 @@ def test_data_parallel_train_step_compiles_2x2(mesh4, monkeypatch, table):
     assert KERNEL in txt
     assert "all-reduce" in txt or "collective-permute" in txt
 
+
+
+@pytest.mark.parametrize("table", [None, "tuned_decision.json"])
+def test_tensor_parallel_serving_step_compiles_2x2(topo, table):
+    """The serving engine's 4-way tensor-parallel decode step at
+    smollm-135m's widths (4 slots, an 80-token view in blocks of 16):
+    its logits reassembly is XLA's all-gather, or with the tuned table
+    the table's schedule of collective-permutes."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.comms import Communicator
+    from repro.configs import ARCHITECTURES
+    from repro.models.registry import build_model
+    from repro.serve import ServeEngine
+
+    mesh = Mesh(np.array(topo.devices), ("model",))
+    rep = NamedSharding(mesh, P())
+    if table:
+        table = os.path.join(os.path.dirname(__file__), "..", "examples",
+                             "artifacts", table)
+    api = build_model(ARCHITECTURES["smollm-135m"])
+
+    def on_mesh(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep)
+
+    params = jax.tree.map(on_mesh, jax.eval_shape(api.init,
+                                                  jax.random.PRNGKey(0)))
+    R, T, bs = 4, 80, 16
+    eng = ServeEngine(api, params, max_active=R, view_len=T, block_size=bs,
+                      mesh=mesh, comm=Communicator.create(artifact=table))
+    vec = jax.ShapeDtypeStruct((R,), jnp.int32, sharding=rep)
+    txt = eng._step.lower(
+        params, jax.tree.map(on_mesh, eng.paged.pools),
+        jax.ShapeDtypeStruct((R, T // bs), jnp.int32, sharding=rep),
+        jax.tree.map(on_mesh, eng.opaque), vec, vec,
+        jax.ShapeDtypeStruct((R,), jnp.bool_, sharding=rep)
+    ).compile().as_text()
+    if table:
+        assert "collective-permute" in txt and "all-gather" not in txt
+    else:
+        assert "all-gather" in txt and "collective-permute" not in txt
