@@ -13,6 +13,17 @@ from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models import encdec, hybrid, moe_model, ssm, transformer, vlm
 
 
+@dataclasses.dataclass(frozen=True)
+class LayerDecode:
+    """``decode_step`` split at its loop over layers, for a family whose
+    whole per-request cache is stacked by layer (every leaf ``(layers,
+    batch, ...)``): a serving engine runs that loop itself, over the
+    state it stores, and updates the state in place."""
+    embed: Callable    # (params, tokens (B, 1)) -> hidden
+    layer: Callable    # (params, l, hidden, layer l's state) -> both new
+    head: Callable     # (params, hidden) -> logits (B, V)
+
+
 @dataclasses.dataclass
 class ModelAPI:
     cfg: ModelConfig
@@ -30,6 +41,9 @@ class ModelAPI:
     # pools through one request's block table (cache keys k, v and
     # block_table), not a dense view gathered for it (hybrid)
     paged_kv: bool = False
+    # decode_step in parts, for a family whose cache is all layer-stacked
+    # state (ssm)
+    layer_decode: Optional[LayerDecode] = None
 
 
 _FAMILY = {
@@ -83,6 +97,10 @@ def build_model(
         if hasattr(mod, "decode_step") else None
     init_cache = functools.partial(mod.init_cache, cfg) \
         if hasattr(mod, "init_cache") else None
+    layer_decode = LayerDecode(*(
+        functools.partial(getattr(mod, f"decode_{part}"), cfg=cfg, **dkw)
+        for part in ("embed", "layer", "head"))) \
+        if hasattr(mod, "decode_layer") else None
 
     # token-prompt prefill for serving; vlm decodes past the prefix as pure
     # text, so its serving prefill is the dense one (the batch-dict
@@ -106,6 +124,7 @@ def build_model(
         prefill=prefill,
         token_kv=cfg.family == "hybrid",
         paged_kv=cfg.family == "hybrid",
+        layer_decode=layer_decode,
     )
 
 

@@ -253,22 +253,52 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
     return init_ssm_state(cfg, batch, cfg.num_layers)
 
 
+# decode_step in its three parts; a serving engine that keeps every
+# request's state stacked by layer runs the loop over layers itself
+# (``ModelAPI.layer_decode``)
+def decode_embed(params, tokens, cfg: ModelConfig, *,
+                 compute_dtype=jnp.bfloat16, **_):
+    from repro.models import transformer as T
+    return T.embed_tokens(params, tokens, cfg, compute_dtype)
+
+
+def _decode_layer(lp, x, state, cfg: ModelConfig, compute_dtype):
+    h = L.rms_norm(x, lp["ln"], cfg.norm_eps)
+    y, ns = ssm_block(h, lp["ssm"], cfg, compute_dtype=compute_dtype,
+                      state=state)
+    return x + y, ns
+
+
+def decode_layer(params, l, x, state, cfg: ModelConfig, *,
+                 compute_dtype=jnp.bfloat16, **_):
+    """Layer ``l`` of :func:`decode_step`, ``state`` its ``{"conv",
+    "ssd"}``: the new hidden and the layer's new state."""
+    lp = jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, l, keepdims=False),
+        params["layers"])
+    return _decode_layer(lp, x, state, cfg, compute_dtype)
+
+
+def decode_head(params, x, cfg: ModelConfig, *, compute_dtype=jnp.bfloat16,
+                **_):
+    from repro.models import transformer as T
+    return T.logits_fn(params, x, cfg, compute_dtype)[:, 0]
+
+
 def decode_step(params, cache, tokens, cfg: ModelConfig, *,
                 compute_dtype=jnp.bfloat16, unroll: bool = False, **_):
-    from repro.models import transformer as T
-    x = T.embed_tokens(params, tokens, cfg, compute_dtype)
+    x = decode_embed(params, tokens, cfg, compute_dtype=compute_dtype)
 
     def body(x, xs):
         lp, conv, ssd_st = xs
-        h = L.rms_norm(x, lp["ln"], cfg.norm_eps)
-        y, ns = ssm_block(h, lp["ssm"], cfg, compute_dtype=compute_dtype,
-                          state={"conv": conv, "ssd": ssd_st})
-        return x + y, (ns["conv"], ns["ssd"])
+        x, ns = _decode_layer(lp, x, {"conv": conv, "ssd": ssd_st}, cfg,
+                              compute_dtype)
+        return x, (ns["conv"], ns["ssd"])
 
     x, (nc, nss) = L.layer_scan(
         body, x, (params["layers"], cache["conv"], cache["ssd"]),
         unroll=unroll)
-    logits = T.logits_fn(params, x, cfg, compute_dtype)[:, 0]
+    logits = decode_head(params, x, cfg, compute_dtype=compute_dtype)
     return logits, {"conv": nc, "ssd": nss}
 
 

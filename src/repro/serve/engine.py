@@ -17,8 +17,8 @@ family cache from ``api.init_cache(batch=1, view_len)`` is split into:
     batch axis (found per leaf by comparing the cache's shapes at batch
     1 and 2; a leaf without one is stacked on a leading axis). So the
     SSM state ``(layers, batch, ...)`` is stored ``(layers, slots, 1,
-    ...)``, and the decode step's scan over layers reads and writes it
-    as it is stored, with no transpose of the whole state;
+    ...)``, and the decode step reads and writes it as it is stored,
+    with no transpose of the whole state;
   * lengths — one engine-owned ``(max_active,)`` vector (per-request
     scalar under vmap), replacing the cache's scalar ``length``.
 
@@ -31,6 +31,19 @@ step donates the KV pools, which it updates in place. The scan over
 layers writes the new state layer by layer into its own output buffer,
 so the step does not donate the old state: on a TPU a donated input
 makes XLA copy the whole new state into the old buffer after the loop.
+
+A family whose whole cache is layer-stacked state (``api.layer_decode``:
+the SSM family) is stepped otherwise: only over the occupied slot
+prefix, with its state updated in place. Slots are handed out lowest
+first, and the step is compiled for a short ladder of slot counts
+(``max_active``, halved while the rung stays at 16 or more), every rung
+when the engine is built. Each step runs the smallest rung above the
+highest occupied slot: a rolled loop over layers carries the donated
+stored state, reads each layer's rows of those slots, decodes them
+vmapped and writes them back in place, and updates their lengths and
+current tokens in the same program. Every other family has one rung,
+all its slots.
+
 Admission writes a request's prefilled state, length and first token
 into its slot with one more jitted program, which donates what it
 updates and so writes only that slot, in place, and its prefilled KV
@@ -70,13 +83,16 @@ host was doing in each idle gap:
       serve.admit.kv        the prefilled KV written into the slot's blocks
       serve.admit.state     the slot's state, length and first token
       serve.admit.first_token   reading the first token back
-    serve.step (active)     one decode step, tokens handed to the scheduler
+    serve.step (active, rung)   one decode step over ``rung`` slots,
+                          tokens handed to the scheduler
       serve.step.dispatch   the jitted step and the token update
       serve.step.readback   reading the step's tokens back
     serve.gc (generation)   a Python garbage collection, wherever it falls
 
 The simulated clock records the same spans. Each run also returns its
-counters (`ServeResult.counters`): ``admissions``, ``decode_steps``,
+counters (`ServeResult.counters`): ``admissions``, ``decode_steps`` by
+rung, ``decode_slots`` (the slots the steps ran, summed; the summed
+active count over it is the share of stepped rows a request owned),
 ``gc_collections`` by generation, ``gc_s`` and ``gc_max_s`` (the
 longest single collection), with or without a profiler; and with paged
 KV, ``kv_blocks_peak`` (the most pool blocks held at once),
@@ -91,6 +107,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import gc
+import heapq
 import time
 from typing import Callable, Optional
 
@@ -103,6 +120,15 @@ from repro.serve.paged_kv import PagedKV, gather_views, write_tokens
 from repro.serve.tp import assemble_logits, decode_requests
 
 PAGED_LEAVES = ("k", "v")
+
+
+def _rungs(max_active: int) -> tuple:
+    """The slot counts a layered step is compiled for: ``max_active``,
+    halved while the rung stays at 16 or more; ascending."""
+    rungs = [max_active]
+    while rungs[-1] // 2 >= 16:
+        rungs.append(rungs[-1] // 2)
+    return tuple(reversed(rungs))
 
 
 def _batch_axis(one, two) -> int:
@@ -187,7 +213,7 @@ class ServeEngine:
         self.lengths = jnp.zeros((R,), jnp.int32)
         self._host_lengths = np.zeros((R,), np.int64)   # self.lengths' copy
         self.cur_tokens = jnp.zeros((R,), jnp.int32)
-        self._free_slots = list(range(R - 1, -1, -1))
+        self._free_slots = list(range(R))      # a min-heap: lowest first
         self._active_mask = np.zeros((R,), bool)
         self._slot_req: dict[int, object] = {}
 
@@ -200,8 +226,19 @@ class ServeEngine:
         self._prefill = jax.jit(
             lambda params, tokens, **extra:
             self.api.prefill(params, tokens, self.view_len, **extra))
-        self._step = self._build_step()
         self._write_slot = self._build_write_slot()
+        if api.layer_decode is None:
+            self.rungs = (R,)
+            self._steps = {R: self._build_step()}
+        else:
+            assert not self.paged_names and not self._has_length and all(
+                ax == 1 for ax in jax.tree.leaves(self._slot_axes)), \
+                "a layered decode needs every cache leaf (layers, batch,...)"
+            self.rungs = _rungs(R)
+            self._steps = {b: self._build_layer_step(b) for b in self.rungs}
+            if all(isinstance(a, jax.Array)
+                   for a in jax.tree.leaves(params)):
+                self._warm_rungs()
 
     @property
     def kv_view_bytes(self) -> int:
@@ -223,10 +260,11 @@ class ServeEngine:
 
     def decode_requests(self):
         """The decode-step collective requests (for ``explain()``) — same
-        builders as the executed step, batch = the slot count."""
+        builders as the executed steps, batch = each rung's slot count."""
         cfg = self.api.cfg
-        return decode_requests(self.max_active, cfg.d_model, cfg.vocab_size,
-                               max(self._tp, 2), axis=self._axis)
+        return [r for b in self.rungs
+                for r in decode_requests(b, cfg.d_model, cfg.vocab_size,
+                                         max(self._tp, 2), axis=self._axis)]
 
     # -- jitted step -------------------------------------------------------
 
@@ -284,6 +322,68 @@ class ServeEngine:
                 check_vma=False)
         return jax.jit(step, donate_argnums=(1,))
 
+    def _build_layer_step(self, b):
+        """The step of a family whose whole cache is layer-stacked state
+        (``api.layer_decode``), over slots ``0..b-1``: a rolled loop over
+        layers carries the donated stored state, reads layer ``l``'s rows
+        of those slots, decodes them vmapped (the same ops the vmapped
+        ``decode_step`` runs) and writes them back in place. The slots'
+        lengths and current tokens are updated in the same program."""
+        ld = self.api.layer_decode
+        tp, ax, collective, comm = (self._tp, self._axis, self._collective,
+                                    self._comm)
+
+        def step(params, opaque, lengths, tokens, active):
+            x = jax.vmap(lambda t: ld.embed(params, t[None, None]))(
+                tokens[:b])
+
+            def layer(l, carry):
+                x, opq = carry
+                rows = jax.tree.map(lambda st: jax.lax.dynamic_slice(
+                    st, (l, 0) + (0,) * (st.ndim - 2),
+                    (1, b) + st.shape[2:])[0], opq)
+                x, new = jax.vmap(lambda x, s: ld.layer(params, l, x, s))(
+                    x, rows)
+                opq = jax.tree.map(lambda st, n: jax.lax.dynamic_update_slice(
+                    st, n[None].astype(st.dtype),
+                    (l, 0) + (0,) * (st.ndim - 2)), opq, new)
+                return x, opq
+
+            n_layers = jax.tree.leaves(opaque)[0].shape[0]
+            x, opaque = jax.lax.fori_loop(0, n_layers, layer, (x, opaque))
+            logits = jax.vmap(lambda x: ld.head(params, x))(x)[:, 0]
+            if tp:
+                logits = assemble_logits(logits, comm, collective, ax, tp)
+            next_tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            lens = lengths[:b]
+            lengths = jax.lax.dynamic_update_slice(
+                lengths, jnp.where(active, lens + 1, lens), (0,))
+            tokens = jax.lax.dynamic_update_slice(
+                tokens, jnp.where(active, next_tok, tokens[:b]), (0,))
+            return logits, next_tok, opaque, lengths, tokens
+
+        if self._tp:
+            from jax.sharding import PartitionSpec as P
+            from repro import compat
+            step = compat.shard_map(
+                step, mesh=self._mesh,
+                in_specs=(P(),) * 5, out_specs=(P(),) * 5,
+                check_vma=False)
+        return jax.jit(step, donate_argnums=(1, 2, 3))
+
+    def _warm_rungs(self):
+        """Compile every rung before any request, by stepping it with no
+        slot active (which leaves lengths and tokens as they are; a free
+        slot's state is overwritten at admission). Twice: the outputs of
+        the first call may be committed to a device where the fresh state
+        was not, and a committed argument is another entry of the
+        program's cache."""
+        for b in self.rungs:
+            for _ in range(2):
+                _, _, self.opaque, self.lengths, self.cur_tokens = \
+                    self._steps[b](self.params, self.opaque, self.lengths,
+                                   self.cur_tokens, jnp.zeros((b,), bool))
+
     def _build_write_slot(self):
         """One program that puts an admitted request's prefilled state,
         length and first token into its slot, in place."""
@@ -310,10 +410,10 @@ class ServeEngine:
             raise RuntimeError("no free request slot")
         assert req.prompt_len <= self.view_len, \
             f"prompt {req.prompt_len} exceeds KV view {self.view_len}"
-        slot = self._free_slots[-1]
+        slot = self._free_slots[0]
         if self.paged is not None and not self.paged.admit(slot):
             raise RuntimeError("KV block pool exhausted")
-        self._free_slots.pop()
+        heapq.heappop(self._free_slots)
         with span("serve.prefill"):
             tokens = jnp.asarray(np.asarray(req.prompt, np.int32))[None]
             logits, cache = self._prefill(self.params, tokens,
@@ -337,7 +437,7 @@ class ServeEngine:
             self.paged.release(slot)
         self._active_mask[slot] = False
         self._slot_req.pop(slot, None)
-        self._free_slots.append(slot)
+        heapq.heappush(self._free_slots, slot)
 
     @property
     def live_kv_blocks(self) -> int:
@@ -349,26 +449,38 @@ class ServeEngine:
                           self.view_len)
         return int(((live + bs - 1) // bs).sum())
 
+    def rung(self) -> int:
+        """The slot count the next step runs: the smallest rung above the
+        highest occupied slot."""
+        occupied = np.flatnonzero(self._active_mask)
+        need = occupied[-1] + 1 if occupied.size else 1
+        return next(b for b in self.rungs if b >= need)
+
     def step(self):
         """One decode step for every active slot. Returns {slot: token}."""
+        b = self.rung()
         with span("serve.step.dispatch"):
-            tables = (self.paged.tables if self.paged is not None
-                      else jnp.zeros((self.max_active, 1), jnp.int32))
-            pools = self.paged.pools if self.paged is not None else {}
-            active = jnp.asarray(self._active_mask)
-            logits, next_tok, new_pools, new_opq, new_lens = self._step(
-                self.params, pools, tables, self.opaque, self.lengths,
-                self.cur_tokens, active)
-            if self.paged is not None:
-                self.paged.pools = new_pools
-            self.opaque = new_opq
-            self.lengths = new_lens
-            self.cur_tokens = jnp.where(active, next_tok, self.cur_tokens)
+            active = jnp.asarray(self._active_mask[:b])
+            if self.api.layer_decode is not None:
+                _, next_tok, self.opaque, self.lengths, self.cur_tokens = \
+                    self._steps[b](self.params, self.opaque, self.lengths,
+                                   self.cur_tokens, active)
+            else:
+                tables = (self.paged.tables if self.paged is not None
+                          else jnp.zeros((self.max_active, 1), jnp.int32))
+                pools = self.paged.pools if self.paged is not None else {}
+                _, next_tok, new_pools, self.opaque, self.lengths = \
+                    self._steps[b](self.params, pools, tables, self.opaque,
+                                   self.lengths, self.cur_tokens, active)
+                if self.paged is not None:
+                    self.paged.pools = new_pools
+                self.cur_tokens = jnp.where(active, next_tok,
+                                            self.cur_tokens)
             self._host_lengths[self._active_mask] += 1
         with span("serve.step.readback"):
             toks = np.asarray(next_tok)  # sync point: honest token latency
-        return {s: int(toks[s]) for s in range(self.max_active)
-                if self._active_mask[s]}
+        return {int(s): int(toks[s])
+                for s in np.flatnonzero(self._active_mask)}
 
     # -- serving loop ------------------------------------------------------
 
@@ -443,7 +555,9 @@ class ServeEngine:
                 if stepped:
                     if self.api.paged_kv and self.paged is not None:
                         counters.inc("kv_blocks_read", self.live_kv_blocks)
-                    with span("serve.step", active=len(sched.active)):
+                    rung = self.rung()
+                    with span("serve.step", active=len(sched.active),
+                              rung=rung):
                         toks = self.step()
                         if sim:
                             now += cost_model("decode", len(toks))
@@ -454,7 +568,8 @@ class ServeEngine:
                             if (req is not None
                                     and len(req.generated) < req.max_new):
                                 sched.record_token(req, tok, now)
-                        counters.inc("decode_steps")
+                        counters.inc("decode_steps", label=str(rung))
+                        counters.inc("decode_slots", rung)
                 with span("serve.schedule"):
                     if stepped:
                         sched.note_decode(now)

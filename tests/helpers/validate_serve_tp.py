@@ -49,7 +49,7 @@ def trace():
 VIEW = -(-max(r.prompt_len + r.max_new for r in trace()) // BLOCK) * BLOCK
 
 
-def oracle(req):
+def oracle(req, api=api, params=params):
     tokens = jnp.asarray(np.asarray(req.prompt, np.int32))[None]
     logits, cache = api.prefill(params, tokens, VIEW)
     tok = int(jnp.argmax(logits[0, -1]))
@@ -67,14 +67,15 @@ def serve(**engine_kw):
     the logits of every decode step."""
     engine = ServeEngine(api, params, max_active=MAX_ACTIVE, view_len=VIEW,
                          block_size=BLOCK, **engine_kw)
-    step, logits = engine._step, []
+    (rung, step), = engine._steps.items()
+    logits = []
 
     def recording(*args):
         out = step(*args)
         logits.append(np.asarray(out[0]))
         return out
 
-    engine._step = recording
+    engine._steps[rung] = recording
     sched = Scheduler(trace(), max_active=MAX_ACTIVE,
                       token_budget=MAX_ACTIVE * VIEW)
     engine.run(sched, cost_model=lambda kind, n: 1e-3)
@@ -112,6 +113,32 @@ for collective in ("all_gather", "all_reduce"):
           f"serve_tp/{collective} bit-identical={identical}")
     if not identical:
         fails.append(collective)
+
+# the SSM family's layered step under the shard_map, at every rung: 24
+# requests at once over 32 slots fill past slot 16, so the 16- and
+# 32-slot programs both run
+ssm_api = build_model(get_config("mamba2-130m").reduced())
+ssm_params = ssm_api.init(jax.random.PRNGKey(0))
+ssm_trace = synthetic_trace(24, rate_rps=1e6, vocab=ssm_api.cfg.vocab_size,
+                            prompt_lens=(4, 6), max_new=6, seed=1)
+ssm_want = {r.rid: oracle(r, ssm_api, ssm_params) for r in ssm_trace}
+for collective in ("all_gather", "all_reduce"):
+    ssm_engine = ServeEngine(ssm_api, ssm_params, max_active=32,
+                             view_len=VIEW, block_size=BLOCK, mesh=mesh,
+                             comm=comm, collective=collective)
+    sched = Scheduler(synthetic_trace(
+        24, rate_rps=1e6, vocab=ssm_api.cfg.vocab_size, prompt_lens=(4, 6),
+        max_new=6, seed=1), max_active=32, token_budget=32 * VIEW)
+    res = ssm_engine.run(sched, cost_model=lambda kind, n: 1e-3)
+    got = {r.rid: list(r.generated) for r in sched.finished}
+    rungs = {label for name, label, _ in res.counters.items()
+             if name == "decode_steps"}
+    identical = got == ssm_want and rungs == {"16", "32"}
+    print(("OK  " if identical else "FAIL"),
+          f"serve_tp/ssm/{collective} rungs={sorted(rungs)} "
+          f"bit-identical={got == ssm_want}")
+    if not identical:
+        fails.append(f"ssm/{collective}")
 
 # the executed decode plan resolves in the small-message regime and picks
 # a different algorithm than the MB-scale training regime

@@ -196,14 +196,15 @@ def test_tp_decode_single_device_wiring():
     def serve(**kw):
         eng = ServeEngine(api, params, max_active=2, view_len=12,
                           block_size=4, **kw)
-        step, logits = eng._step, []
+        (rung, step), = eng._steps.items()
+        logits = []
 
         def recording(*args):
             out = step(*args)
             logits.append(np.asarray(out[0]))
             return out
 
-        eng._step = recording
+        eng._steps[rung] = recording
         eng.run(Scheduler(synthetic_trace(3, rate_rps=500.0,
                                           vocab=cfg.vocab_size,
                                           prompt_lens=(3, 5), max_new=5,

@@ -125,7 +125,10 @@ def test_serve_cli(tmp_path, slo):
         assert rec["ttft_ms"] >= 0.0 and rec["finish_s"] >= rec["admit_s"]
     # the engine run's counters, for an operator without a profiler
     assert doc["counters"]["admissions"] == 6
-    assert doc["counters"]["decode_steps"] >= 5
+    # one rung (2 slots): every step ran all of them
+    assert doc["counters"]["decode_steps{2}"] >= 5
+    assert doc["counters"]["decode_slots"] \
+        == 2 * doc["counters"]["decode_steps{2}"]
 
 
 def test_serve_cli_continuous_tp_slo_8dev(tmp_path):

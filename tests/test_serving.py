@@ -428,12 +428,18 @@ def state_engine(request):
 
 
 def _step_program(engine):
+    R = engine.max_active
+    if engine.api.layer_decode is not None:
+        # the state, the slots' lengths and their tokens, donated
+        return engine._steps[R].lower(
+            engine.params, engine.opaque, engine.lengths, engine.cur_tokens,
+            jnp.zeros((R,), bool)), 2 * 4 * R
     tables = (engine.paged.tables if engine.paged is not None
-              else jnp.zeros((engine.max_active, 1), jnp.int32))
+              else jnp.zeros((R, 1), jnp.int32))
     pools = engine.paged.pools if engine.paged is not None else {}
-    return engine._step.lower(
+    return engine._steps[R].lower(
         engine.params, pools, tables, engine.opaque, engine.lengths,
-        engine.cur_tokens, jnp.zeros((engine.max_active,), bool)), None
+        engine.cur_tokens, jnp.zeros((R,), bool)), None
 
 
 def _write_program(engine):
@@ -455,9 +461,11 @@ def test_engine_state_stays_in_cache_layout_and_in_place(state_engine,
     """The slots sit on the cache's batch axis (axis 1 of the SSM state),
     so neither the decode step nor the admission's slot write transposes
     a stored state leaf. The slot write aliases the stored state to its
-    output; the step writes a new state and aliases only the KV pools,
-    which it updates in place (a donated state costs a whole-state copy
-    on the chip: tests/test_tpu_compile.py)."""
+    output, and so does the SSM family's step, whose loop over layers
+    carries the state and updates it in place; the hybrid's step (a scan
+    over layers, whose donated state would cost a whole-state copy on the
+    chip: tests/test_tpu_compile.py) writes a new state and aliases only
+    the KV pools, which it updates in place."""
     engine, cfg = state_engine
     leaves = jax.tree.leaves(engine.opaque)
     R = engine.max_active
@@ -513,6 +521,108 @@ def test_engine_slot_reuse_writes_only_that_slot():
         got_c.append(toks[slot_c])
     assert got_b == _oracle_tokens(api, params, b, view_len, None)
     assert got_c == _oracle_tokens(api, params, c, view_len, None)
+
+
+# ---------------------------------------------------------------------------
+# the SSM family steps only the occupied slot prefix, at a few rungs
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("max_active,rungs", [
+    (64, (16, 32, 64)), (32, (16, 32)), (48, (24, 48)), (31, (31,)),
+    (8, (8,))])
+def test_rung_ladder_halves_down_to_16_slots(max_active, rungs):
+    from repro.serve.engine import _rungs
+    assert _rungs(max_active) == rungs
+
+
+def _burst_trace(vocab):
+    """34 requests at once (slots 0-33, past 16 and 32), most of them
+    done after one step: then slot 31 holds the highest of them, and then
+    slot 0; one more arrives meanwhile and takes freed slot 1."""
+    rng = np.random.default_rng(5)
+    new = [4] + [2] * 30 + [3] + [2] * 2
+    due_new = [(0.0, n) for n in new] + [(0.02, 2)]
+    return [Request(rid=i, arrival_s=t, max_new=n, prompt=tuple(
+        int(x) for x in rng.integers(0, vocab, 4)))
+        for i, (t, n) in enumerate(due_new)]
+
+
+def test_layered_engine_steps_the_occupied_prefix():
+    """mamba2 over 64 slots: requests fill past slots 16 and 32, retire
+    mid-run and new ones take the freed low slots. Each admission takes
+    the lowest free slot; each step runs the smallest rung above the
+    highest occupied slot (every rung runs at least once); no program
+    compiles after the engine is built; the counters add up to the steps
+    taken; and every request's tokens are its dense batch-1 oracle's."""
+    cfg = ARCHITECTURES["mamba2-130m"].reduced()
+    api = build_model(cfg)
+    # committed to the device, as the benchmark's weights are
+    params = jax.device_put(api.init(jax.random.PRNGKey(0)),
+                            jax.devices()[0])
+    view_len, R = 16, 64
+    engine = ServeEngine(api, params, max_active=R, view_len=view_len,
+                         block_size=BLOCK)
+    assert engine.rungs == (16, 32, 64)
+    programs = dict(engine._steps)
+    compiled = {b: f._cache_size() for b, f in programs.items()}
+    assert all(n >= 1 for n in compiled.values())
+
+    ran, slots, reused = [], [], []
+    admit = engine.admit
+
+    def lowest_free_admit(req):
+        occupied = set(np.flatnonzero(engine._active_mask))
+        slot = admit(req)
+        assert slot == min(set(range(R)) - occupied), (slot, occupied)
+        reused.append(slot in slots)
+        slots.append(slot)
+        return slot
+    engine.admit = lowest_free_admit
+
+    def recording(b):
+        def run(*args):
+            high = np.flatnonzero(engine._active_mask)[-1]
+            ran.append((b, high))
+            return programs[b](*args)
+        return run
+    engine._steps = {b: recording(b) for b in programs}
+
+    trace = _burst_trace(cfg.vocab_size)
+    sched = Scheduler(trace, max_active=R, token_budget=R * view_len)
+    res = engine.run(sched, cost_model=lambda kind, n: 1e-3)
+
+    assert len(sched.finished) == len(trace)
+    assert slots[-1] == 1 and reused[-1]
+    for b, high in ran:
+        assert b == min(r for r in engine.rungs if r >= high + 1), (b, high)
+    assert [b for b, _ in ran] == [64, 32, 16]
+    assert {b: f._cache_size() for b, f in programs.items()} == compiled
+    c = res.counters
+    assert c.total("decode_steps") == len(ran)
+    for b in engine.rungs:
+        assert c.get("decode_steps", label=str(b)) == sum(
+            r == b for r, _ in ran)
+    assert c.get("decode_slots") == sum(b for b, _ in ran)
+    want = {r.rid: _oracle_tokens(api, params, r, view_len, None)
+            for r in _burst_trace(cfg.vocab_size)}
+    assert {r.rid: r.generated for r in sched.finished} == want
+
+
+def test_paged_families_keep_one_full_width_step():
+    """A family with paged KV (the hybrid here) is stepped over all its
+    slots by one program whatever they hold; it still takes the lowest
+    free slot."""
+    cfg = ARCHITECTURES["zamba2-2.7b"].reduced()
+    api = build_model(cfg, attn_impl="xla")
+    assert api.layer_decode is None
+    params = api.init(jax.random.PRNGKey(0))
+    engine = ServeEngine(api, params, max_active=32, view_len=8,
+                         block_size=BLOCK)
+    assert engine.rungs == (32,) and set(engine._steps) == {32}
+    reqs = [Request(rid=i, arrival_s=0.0, prompt=(1, 2, 3), max_new=2)
+            for i in range(3)]
+    assert [engine.admit(r) for r in reqs] == [0, 1, 2]
+    engine.release(1)
+    assert engine.rung() == 32 and engine.admit(reqs[1]) == 1
 
 
 @pytest.mark.slow
@@ -628,7 +738,10 @@ def test_engine_run_counters_and_queue_wait(tiny_engine):
                      cost_model=costs)
     c = res.counters
     assert c.get("admissions") == len(trace) == costs.calls["prefill"]
-    assert c.get("decode_steps") == costs.calls["decode"] > 0
+    # one rung: every step ran both slots
+    assert c.get("decode_steps", label="2") == costs.calls["decode"] > 0
+    assert c.total("decode_steps") == costs.calls["decode"]
+    assert c.get("decode_slots") == 2 * costs.calls["decode"]
     # the first request meets an idle engine: admitted on arrival, its
     # first token one prefill later
     first = min(trace, key=lambda r: r.arrival_s)
